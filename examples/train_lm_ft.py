@@ -1,7 +1,8 @@
-"""End-to-end driver: train a ~100M-parameter LM for a few hundred steps
-under the unified FT framework (checkpoint + replication), with injected
-failures, and verify the FT theorem: final parameters match a failure-free
-run exactly.
+"""End-to-end driver: train a small LM (the reduced xlstm-350m config, about
+0.6 M parameters, sized for a CPU) for a few hundred steps under the
+unified FT framework (checkpoint + replication), with injected failures,
+and verify the FT theorem: final parameters match a failure-free run
+exactly.  ``chip_smoke.py`` makes the same check at full width on a TPU.
 
 This is the training analogue of the paper's HPCG experiments, driven
 through the unified ``repro.ft`` API (FTSession + TrainWorkload): the
@@ -27,8 +28,6 @@ ap.add_argument("--steps", type=int, default=200)
 ap.add_argument("--arch", default="xlstm-350m")
 args = ap.parse_args()
 
-# xlstm-350m reduced ~= a few M params; bump width for a ~100M-class model
-# on CPU budgets use the reduced config; pass --full on a real pod.
 kills = {args.steps // 4: [0],                  # cmp slice dies -> promote
          args.steps // 2: [1, 9],               # cmp + its replica -> restart
          3 * args.steps // 4: [10]}             # replica dies -> drop

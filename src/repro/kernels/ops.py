@@ -1,14 +1,12 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled (`interpret=False`); on CPU (this container,
-and any test environment) they run in interpret mode, executing the kernel
-body in Python for correctness validation. ``backend="ref"`` forces the
-pure-jnp oracle — models use that path for dry-run lowering so the compiled
-HLO stays analyzable on the CPU backend.
+``backend="auto"`` runs the compiled kernels (`interpret=False`) and is
+valid only on a TPU: anywhere else it raises rather than quietly running
+the interpreter. ``backend="interpret"`` executes the kernel body in Python
+for correctness validation on the CPU (the tests pass it explicitly), and
+``backend="ref"`` forces the pure-jnp oracle.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 
@@ -18,17 +16,16 @@ from repro.kernels.mamba_scan import mamba_chunk_scan as _mamba_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def _resolve(backend: str) -> str:
-    if backend == "auto":
-        return "pallas" if _on_tpu() else "interpret"
-    return backend
+    if backend != "auto":
+        return backend
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise RuntimeError(
+            f"backend='auto' runs the Pallas kernels compiled and needs a "
+            f"TPU, but JAX's default backend is {platform!r}; pass "
+            f"backend='interpret' or backend='ref' off the chip")
+    return "pallas"
 
 
 def attention(q, k, v, *, causal=True, window=0, q_block=128, kv_block=128,

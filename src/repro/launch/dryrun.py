@@ -21,7 +21,7 @@ from repro.distributed import sharding as shard_rules          # noqa: E402
 from repro.distributed.sharding import use_batch_axes           # noqa: E402
 from repro.launch import hlo_cost                              # noqa: E402
 from repro.launch import roofline as rl                        # noqa: E402
-from repro.launch.mesh import (activate_mesh, make_production_mesh,  # noqa: E402
+from repro.launch.mesh import (make_production_mesh,  # noqa: E402
                                make_replica_split_mesh)
 from repro.launch.step_fns import (make_decode_step, make_prefill_step,      # noqa: E402
                                    make_train_step)
@@ -66,7 +66,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
             in_shardings=(p_sh, opt_sh, in_sh),
             out_shardings=(p_sh, opt_sh, NamedSharding(mesh, P())),
             donate_argnums=(0, 1) if donate else ())
-        with activate_mesh(mesh), use_batch_axes(
+        with jax.set_mesh(mesh), use_batch_axes(
                 shard_rules.batch_axes(mesh, replication)):
             lowered = jitted.lower(abstract_params, opt_abstract, in_specs)
     elif shape.kind == "prefill":
@@ -80,7 +80,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
             (shape.global_batch, 1, cfg.vocab_size), mesh, replication))
         jitted = jax.jit(step, in_shardings=(p_sh, in_sh),
                          out_shardings=(logits_sh, cache_sh))
-        with activate_mesh(mesh), use_batch_axes(
+        with jax.set_mesh(mesh), use_batch_axes(
                 shard_rules.batch_axes(mesh, replication)):
             lowered = jitted.lower(abstract_params, in_specs)
     else:  # decode
@@ -97,7 +97,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
             in_shardings=(p_sh, cache_sh, in_sh["tokens"], in_sh["pos"]),
             out_shardings=(logits_sh, cache_sh),
             donate_argnums=(1,) if donate else ())
-        with activate_mesh(mesh), use_batch_axes(
+        with jax.set_mesh(mesh), use_batch_axes(
                 shard_rules.batch_axes(mesh, replication)):
             lowered = jitted.lower(abstract_params, cache_abs,
                                    in_specs["tokens"], in_specs["pos"])
@@ -112,8 +112,6 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax <= 0.4 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     # trip-count-aware analysis (XLA's cost_analysis counts loop bodies once;
     # see launch/hlo_cost.py) — flops/bytes/collectives are all per-device

@@ -13,30 +13,6 @@ from jax.sharding import Mesh
 from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD
 
 
-def activate_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh, across JAX
-    versions: ``jax.set_mesh`` (>= 0.6), ``jax.sharding.use_mesh``
-    (0.5.x), or the legacy ``with mesh:`` thread-local (<= 0.4, where Mesh
-    is itself a context manager)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
-def make_auto_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the installed JAX has
-    typed axes (>= 0.5); plain ``make_mesh`` otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

@@ -31,10 +31,11 @@ import numpy as np
 from repro.clock import VirtualClock, pricing_from_ft
 from repro.comm import CollectiveEngine, NOTHING, ReplicaTransport
 from repro.configs import RunConfig, get_arch
-from repro.configs.base import FTConfig, ShapeConfig
+from repro.configs.base import FTConfig, ModelConfig, ShapeConfig
 from repro.core.coordinator import ClusterTopology
 from repro.core.replica_map import ReplicaMap
 from repro.ft import DecodeWorkload, FTSession, StepKillInjector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step_fns import make_decode_step, make_prefill_step
 
 
@@ -111,6 +112,16 @@ class BatchFanout:
         return got[cmp_w]
 
 
+def serve_run_config(cfg: ModelConfig, *, batch: int,
+                     prompt_len: int) -> RunConfig:
+    """The RunConfig the server compiles its prefill and decode from."""
+    shape = ShapeConfig("serve", seq_len=prompt_len, global_batch=batch,
+                        kind="prefill")
+    return RunConfig(model=cfg, shape=shape, remat="none",
+                     kv_block=min(prompt_len, 128),
+                     seq_chunk=min(prompt_len, 512))
+
+
 class ReplicatedServer:
     """Model plumbing (prefill/decode jits, params) + a thin ``generate``
     that delegates all fault tolerance to FTSession."""
@@ -122,11 +133,7 @@ class ReplicatedServer:
         if reduced:
             cfg = cfg.reduced()
         self.cfg = cfg
-        shape = ShapeConfig("serve", seq_len=prompt_len, global_batch=batch,
-                            kind="prefill")
-        run = RunConfig(model=cfg, shape=shape, remat="none",
-                        kv_block=min(prompt_len, 128),
-                        seq_chunk=min(prompt_len, 512))
+        run = serve_run_config(cfg, batch=batch, prompt_len=prompt_len)
         self.prefill, self.model = make_prefill_step(run)
         self.decode, _ = make_decode_step(run)
         self.prefill = jax.jit(self.prefill)
@@ -207,6 +214,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -216,6 +224,7 @@ def main(argv=None):
                     help="price fan-out + session time over this topo graph "
                          "(flat|fattree|dragonfly|torus3d)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     srv = ReplicatedServer(args.arch, reduced=args.reduced, batch=args.batch,
                            prompt_len=args.prompt_len,

@@ -1,8 +1,9 @@
 """Training driver: any assigned arch, any FT mode, on the current devices.
 
-On this container it trains *reduced* configs end-to-end on CPU (the
-examples use it); on a real pod the same driver trains the full config —
-the mesh/sharding path is identical to the dry-run's.
+By default it trains *reduced* configs (the CPU tests and examples use
+it); ``--full`` trains the published config.  Everything, the replica's
+copy of the state included, lives on JAX's default device: one TPU in
+``chip_smoke.py``.
 
 The FT loop is the unified ``repro.ft`` API: ``build_workload`` wraps the
 jitted train step as a ``TrainWorkload``; ``build_session`` pairs it with an
@@ -23,12 +24,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import RunConfig, get_arch
-from repro.configs.base import FTConfig, ShapeConfig
+from repro.configs.base import FTConfig, ModelConfig, ShapeConfig
 from repro.core.ft_runtime import FTTrainer
 from repro.data import DataConfig, TokenSource
 from repro.ft import FTSession, TrainWorkload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step_fns import make_train_step
 from repro.optim import adamw
+
+
+def train_run_config(cfg: ModelConfig, *, batch: int, seq: int,
+                     lr: float = 1e-3) -> RunConfig:
+    """The RunConfig the train driver compiles its step from."""
+    shape = ShapeConfig("cli", seq_len=seq, global_batch=batch, kind="train")
+    return RunConfig(model=cfg, shape=shape, remat="none",
+                     seq_chunk=min(seq, 512), kv_block=min(seq, 128),
+                     learning_rate=lr)
 
 
 def build_workload(arch: str, *, reduced: bool = True, batch: int = 8,
@@ -37,11 +48,8 @@ def build_workload(arch: str, *, reduced: bool = True, batch: int = 8,
     cfg = get_arch(arch)
     if reduced:
         cfg = cfg.reduced()
-    shape = ShapeConfig("cli", seq_len=seq, global_batch=batch, kind="train")
-    run = RunConfig(model=cfg, shape=shape, remat="none",
-                    seq_chunk=min(seq, 512), kv_block=min(seq, 128),
-                    learning_rate=lr)
-    step_fn, model = make_train_step(run)
+    step_fn, model = make_train_step(
+        train_run_config(cfg, batch=batch, seq=seq, lr=lr))
     jitted = jax.jit(step_fn, donate_argnums=(0, 1))
     data = TokenSource(DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
                                   global_batch=batch, seed=seed))
@@ -115,6 +123,7 @@ def main(argv=None):
                     help="step:worker[,worker...] failure injection")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     kills = {}
     for spec in args.kill:
@@ -129,6 +138,7 @@ def main(argv=None):
     # repro: allow[wallclock] -- genuine wall measurement
     t0 = time.perf_counter()
     rep = session.run(workload, args.steps)
+    jax.block_until_ready(rep.final_state)
     # repro: allow[wallclock] -- genuine wall measurement
     dt = time.perf_counter() - t0
     print(f"arch={args.arch} mode={args.ft_mode} steps={rep.steps} "

@@ -66,12 +66,8 @@ def _dispatch_one(cfg: ModelConfig, gates_logits: jnp.ndarray, seq: int):
 
 def _mesh_for_shard_map():
     """Usable mesh for the explicit-TP path, or None (single-device tests)."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:          # pragma: no cover
-        return None
-    names = getattr(m, "axis_names", ()) if m is not None else ()
-    if "model" not in names or dict(m.shape).get("model", 1) <= 1:
+    m = jax.sharding.get_abstract_mesh()
+    if "model" not in m.axis_names or m.shape["model"] <= 1:
         return None
     return m
 

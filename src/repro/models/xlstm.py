@@ -162,7 +162,11 @@ def slstm_params(cfg: ModelConfig, rng, dtype) -> Params:
     return {
         "ln": L.rmsnorm_params(d, dtype),
         "w_gates": L._dense_init(r[0], (d, 4 * d), dtype),   # z i f o
-        "r_gates": L._dense_init(r[1], (h, dh, 4 * dh), dtype),
+        # one [dh, 4dh] recurrent matrix per head, so fan-in is dh (not the
+        # head count): larger weights make the backward pass of the time
+        # recurrence overflow to NaN at sequence lengths near 1024
+        "r_gates": jax.vmap(lambda k: L._dense_init(k, (dh, 4 * dh), dtype))(
+            jax.random.split(r[1], h)),
         "b_gates": jnp.zeros((4 * d,), dtype),
         "up": L.mlp_params(d, f_in, r[2], dtype),
     }

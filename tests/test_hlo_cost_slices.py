@@ -69,9 +69,9 @@ x = jax.random.normal(jax.random.key(1), (4, 16, 64), jnp.float32)
 
 local = MOE._moe_apply_local(cfg, p, x)          # single-device reference
 
-from repro.launch.mesh import activate_mesh, make_auto_mesh
-mesh = make_auto_mesh((4, 2), ("data", "model"))
-with activate_mesh(mesh), use_batch_axes(("data",)):
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh), use_batch_axes(("data",)):
     sharded = jax.jit(lambda p, x: MOE.moe_apply(cfg, p, x))(p, x)
 
 np.testing.assert_allclose(np.asarray(local), np.asarray(sharded),

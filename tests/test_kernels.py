@@ -133,3 +133,19 @@ def test_mamba_chunk_invariance():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(h32), np.asarray(h64),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------------------ backend="auto"
+
+@pytest.mark.parametrize("call", [
+    lambda x: ops.attention(x, x, x),
+    lambda x: ops.rmsnorm(x, x[0, 0, 0]),
+    lambda x: ops.mamba_chunk_scan(x, x[:, :, 0], x[:, :, 0], x[..., 0],
+                                   x[..., 0], chunk=128),
+], ids=["attention", "rmsnorm", "mamba_chunk_scan"])
+def test_auto_backend_refuses_off_tpu(call):
+    """``auto`` means compiled Pallas on a TPU; elsewhere it must raise
+    instead of quietly running the interpreter (tests run on the CPU)."""
+    x = jnp.zeros((1, 2, 128, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        call(x)

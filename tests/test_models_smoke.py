@@ -123,3 +123,21 @@ def test_moe_capacity_drops_are_bounded():
     gl = jax.random.normal(key, (128, cfg.n_experts), jnp.float32) * 0.01
     flat_e, slot, w, keep, cap = MOE._dispatch_one(cfg, gl, 128)
     assert float(keep.mean()) > 0.85
+
+
+def test_slstm_backward_finite_at_full_width():
+    """One sLSTM block at xlstm-350m's published width (4 heads of 256)
+    over 1024 steps, the chip smoke's training length: the backward pass
+    through the time recurrence must stay finite."""
+    from repro.models import xlstm
+    cfg = ARCHS["xlstm-350m"]
+    p = xlstm.slstm_params(cfg, jax.random.key(0), jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(1), (1, 1024, cfg.d_model),
+                          jnp.float32).astype(jnp.bfloat16)
+
+    def loss(p, x):
+        return jnp.sum(xlstm.slstm_apply(cfg, p, x).astype(jnp.float32))
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, x)
+    for g in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(g, np.float32)).all()

@@ -72,7 +72,8 @@ def test_train_cli(tmp_path):
          "codeqwen1.5-7b", "--steps", "8", "--seq", "32", "--batch", "4",
          "--ft-mode", "combined", "--ckpt-dir", str(tmp_path / "ck"),
          "--ckpt-interval", "3", "--kill", "3:0", "--kill", "6:8"],
-        env=ENV, cwd=ROOT, capture_output=True, text=True, timeout=420)
+        env=dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")),
+        cwd=ROOT, capture_output=True, text=True, timeout=420)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "promotions=1" in proc.stdout
     assert "restarts=1" in proc.stdout
