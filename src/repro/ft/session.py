@@ -21,7 +21,6 @@ failover (repro.launch.serve now drives a DecodeWorkload through here).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, List, Optional
 
@@ -33,6 +32,7 @@ from repro.core.replica_map import ReplicaMap
 from repro.core.shrink import plan_recovery
 from repro.ft.injector import FailureInjector, as_injector
 from repro.ft.strategy import FTStrategy, make_strategy
+from repro.obs import span
 
 
 @dataclass
@@ -54,7 +54,6 @@ class RunReport:
     restarts: int = 0
     ckpt_writes: int = 0
     rolled_back_steps: int = 0
-    wall_s: float = 0.0
     ckpt_s: float = 0.0
     restore_s: float = 0.0
     final_state: Any = None
@@ -149,12 +148,10 @@ class FTSession:
         self.pricing = pricing_from_ft(self.ft, self.topology)
         self.clock = VirtualClock(cost_model=self.pricing.cost_model)
 
-    # -- main loop -----------------------------------------------------------
-
-    def run(self, workload, n_steps: int) -> RunReport:
-        rep = RunReport()
-        # repro: allow[wallclock] -- genuine wall measurement
-        wall0 = time.perf_counter()
+    def _start(self, workload, n_steps: int, rep: RunReport):
+        """Everything before the loop: the fabric, the run's clock, the
+        workload's first state, the strategy's start (its replica copy and
+        checkpoint backend) and the injector's horizon."""
         self._init_fabric()                       # re-entrant sessions
         # the run's clock writes straight into the report's ledger
         clock = self.clock = VirtualClock(breakdown=rep.time,
@@ -174,8 +171,7 @@ class FTSession:
         if bind is not None:
             bind(self)
         state = workload.init_state()
-        strat = self.strategy
-        strat.on_start(workload, state, rep)
+        self.strategy.on_start(workload, state, rep)
         # horizon slack (shared formula, repro.clock.injection_horizon):
         # rollbacks extend virtual time past n_steps, so time-indexed
         # schedules get 2x headroom
@@ -183,92 +179,110 @@ class FTSession:
             injection_horizon(n_steps, self.step_time_s,
                               self.ft.ckpt_cost_s),
             self.rmap.alive())
+        return state
+
+    def _recover(self, workload, state, plan, step: int, fresh, rep):
+        """Carry out one recovery plan; returns (state, step)."""
+        obs = self.obs
+        if obs is not None:
+            obs.span(f"recovery.{plan.kind}", "recovery", step=step)
+        rep.events.append(StepEvent(step, plan.kind,
+                                    {"failed": list(fresh),
+                                     "promotions": plan.promotions,
+                                     "restore_backend":
+                                         plan.restore_backend}))
+        state, step = self.strategy.handle_plan(workload, state, plan, step,
+                                                rep)
+        # shrink + message recovery (paper Fig 9 'repair'); ledger-only:
+        # the step-indexed schedule clock ignores it.  A workload that
+        # repairs its own priced transport in apply_plan (repro.pool)
+        # reports the measured per-message drain/replay traffic; everyone
+        # else gets the planner's flat estimate
+        repair_s = plan.repair_cost_s
+        rtrans = getattr(workload, "repair_transport", None)
+        if plan.kind == "promote" and rtrans is not None \
+                and rtrans.cost_model is not None:
+            repair_s = rtrans.take_comm_time()
+        self.clock.charge("repair", repair_s, advance=False, label=plan.kind)
+        if obs is not None:
+            obs.end_span(resumed_step=step)
+        return state, step
+
+    # -- main loop -----------------------------------------------------------
+
+    def run(self, workload, n_steps: int) -> RunReport:
+        rep = RunReport()
+        with span("repro.ft.start"):
+            state = self._start(workload, n_steps, rep)
+        clock, obs, strat = self.clock, self.obs, self.strategy
 
         step = 0
         done_through = 0                  # first step index not yet earned
         while step < n_steps:
             # --- failure intake (injector -> coordinators -> plan) ---------
-            for ev in self.injector.poll(step, clock.now):
-                fresh = self.coords.intercept_failure(list(ev.workers))
-                fresh = [w for w in fresh if w not in self.rmap.dead]
-                if not fresh:
-                    continue
-                rep.failures += len(fresh)
-                if obs is not None:
-                    obs.metrics.inc("failures.kills.worker", len(fresh))
-                    obs.mark("failure", "failure", workers=tuple(fresh),
-                             step=step)
-                # elastic-workload absorption: a task pool can take a
-                # fatal (unreplicated-cmp) death forward — retire the
-                # rank, reassign its work — instead of the world restart
-                # plan_recovery would be forced into
-                absorb = getattr(workload, "absorb_failures", None)
-                if absorb is not None:
-                    state, fresh = absorb(state, list(fresh), step, rep)
+            with span("repro.ft.intake"):
+                events = self.injector.poll(step, clock.now)
+            for ev in events:
+                with span("repro.ft.intake"):
+                    fresh = self.coords.intercept_failure(list(ev.workers))
+                    fresh = [w for w in fresh if w not in self.rmap.dead]
                     if not fresh:
                         continue
-                self.rmap, plan = plan_recovery(
-                    self.rmap, fresh,
-                    last_ckpt_step=strat.last_ckpt_step, current_step=step,
-                    store=strat.recovery_store())
-                if obs is not None:
-                    obs.span(f"recovery.{plan.kind}", "recovery", step=step)
-                rep.events.append(StepEvent(step, plan.kind,
-                                            {"failed": list(fresh),
-                                             "promotions": plan.promotions,
-                                             "restore_backend":
-                                                 plan.restore_backend}))
-                state, step = strat.handle_plan(workload, state, plan,
-                                                step, rep)
-                # shrink + message recovery (paper Fig 9 'repair');
-                # ledger-only: the step-indexed schedule clock ignores
-                # it.  A workload that repairs its own priced transport
-                # in apply_plan (repro.pool) reports the measured
-                # per-message drain/replay traffic; everyone else gets
-                # the planner's flat estimate
-                repair_s = plan.repair_cost_s
-                rtrans = getattr(workload, "repair_transport", None)
-                if plan.kind == "promote" and rtrans is not None \
-                        and rtrans.cost_model is not None:
-                    repair_s = rtrans.take_comm_time()
-                clock.charge("repair", repair_s, advance=False,
-                             label=plan.kind)
-                if obs is not None:
-                    obs.end_span(resumed_step=step)
+                    rep.failures += len(fresh)
+                    if obs is not None:
+                        obs.metrics.inc("failures.kills.worker", len(fresh))
+                        obs.mark("failure", "failure", workers=tuple(fresh),
+                                 step=step)
+                    # elastic-workload absorption: a task pool can take a
+                    # fatal (unreplicated-cmp) death forward — retire the
+                    # rank, reassign its work — instead of the world
+                    # restart plan_recovery would be forced into
+                    absorb = getattr(workload, "absorb_failures", None)
+                    if absorb is not None:
+                        state, fresh = absorb(state, list(fresh), step, rep)
+                        if not fresh:
+                            continue
+                    self.rmap, plan = plan_recovery(
+                        self.rmap, fresh,
+                        last_ckpt_step=strat.last_ckpt_step,
+                        current_step=step, store=strat.recovery_store())
+                with span(f"repro.ft.recover.{plan.kind}"):
+                    state, step = self._recover(workload, state, plan, step,
+                                                fresh, rep)
 
             # --- one workload step (strategy may double-execute) -----------
-            component = "rollback" if step < done_through else "useful"
-            state, metrics = strat.step(workload, state, step)
-            rep.metrics.append(metrics)
-            if step >= done_through:
-                done_through = step + 1
-            step += 1
-            # the schedule clock advances by exactly step_time_s per
-            # executed step (the pre-clock vtime trajectory, bitwise);
-            # re-executed post-rollback steps are booked as 'rollback'
-            clock.charge(component, self.step_time_s)
-            # replica processor-seconds are an explicit ledger component
-            # (the live replicated share of the machine, so the charge
-            # tracks promotions/drops), not a folded efficiency factor —
-            # fig10's overhead row and the Fig 9 split read it directly.
-            # SimRuntime keeps its own accounting; this is FTSession's.
-            n_redundant = len(self.rmap.replicated_ranks())
-            if n_redundant:
-                clock.charge("redundant",
-                             self.step_time_s * n_redundant / self.rmap.n,
-                             advance=False)
-            rep.steps = step
-            if obs is not None:
-                obs.on_step(step - 1, clock.now - self.step_time_s,
-                            self.step_time_s, component == "rollback",
-                            self.rmap.n)
+            with span("repro.ft.step"):
+                component = "rollback" if step < done_through else "useful"
+                state, metrics = strat.step(workload, state, step)
+                rep.metrics.append(metrics)
+                if step >= done_through:
+                    done_through = step + 1
+                step += 1
+                # the schedule clock advances by exactly step_time_s per
+                # executed step (the pre-clock vtime trajectory, bitwise);
+                # re-executed post-rollback steps are booked as 'rollback'
+                clock.charge(component, self.step_time_s)
+                # replica processor-seconds are an explicit ledger
+                # component (the live replicated share of the machine, so
+                # the charge tracks promotions/drops), not a folded
+                # efficiency factor — fig10's overhead row and the Fig 9
+                # split read it directly.  SimRuntime keeps its own
+                # accounting; this is FTSession's.
+                n_redundant = len(self.rmap.replicated_ranks())
+                if n_redundant:
+                    clock.charge("redundant",
+                                 self.step_time_s * n_redundant / self.rmap.n,
+                                 advance=False)
+                rep.steps = step
+                if obs is not None:
+                    obs.on_step(step - 1, clock.now - self.step_time_s,
+                                self.step_time_s, component == "rollback",
+                                self.rmap.n)
 
-            # --- coordinated checkpoint (primary timer) --------------------
-            strat.maybe_checkpoint(workload, state, step, clock.now, rep)
+                # --- coordinated checkpoint (primary timer) ----------------
+                strat.maybe_checkpoint(workload, state, step, clock.now, rep)
 
         rep.final_state = state
-        # repro: allow[wallclock] -- genuine wall measurement
-        rep.wall_s = time.perf_counter() - wall0
         if obs is not None:
             store = strat.recovery_store()
             if store is not None:

@@ -27,6 +27,7 @@ from typing import Any, Optional, Tuple
 from repro.configs.base import FTConfig
 from repro.core import ckpt_policy
 from repro.ft.workload import copy_tree
+from repro.obs import span
 
 
 class FTStrategy:
@@ -110,6 +111,12 @@ class _ReplicaMixin:
     def _simulating(self) -> bool:
         return self.session.simulate_replica
 
+    @staticmethod
+    def _copy(state):
+        """The replica slice's own copy of ``state``."""
+        with span("repro.ft.replica_copy"):
+            return copy_tree(state)
+
     def on_start(self, workload, state, rep) -> None:
         super().on_start(workload, state, rep)
         # a self-replicating workload (repro.pool) already executes its
@@ -118,13 +125,15 @@ class _ReplicaMixin:
         if getattr(workload, "self_replicating", False):
             self.replica_state = None
             return
-        self.replica_state = copy_tree(state) if self._simulating() else None
+        self.replica_state = self._copy(state) if self._simulating() \
+            else None
 
     def step(self, workload, state, t):
         state, metrics = super().step(workload, state, t)
         if self._simulating() and self.replica_state is not None:
             # the replica slice executes the same step on the same data
-            self.replica_state, _ = workload.step(self.replica_state, t)
+            with span("repro.ft.replica_step"):
+                self.replica_state, _ = workload.step(self.replica_state, t)
         return state, metrics
 
     def _on_promote(self, workload, state, plan, step, rep):
@@ -132,7 +141,7 @@ class _ReplicaMixin:
         if self._simulating() and self.replica_state is not None:
             # replica slice state is CURRENT: swap, no rollback
             state = self.replica_state
-            self.replica_state = copy_tree(state) \
+            self.replica_state = self._copy(state) \
                 if self.session.rmap.replication_degree() > 0 else None
         return state, step
 
@@ -140,7 +149,7 @@ class _ReplicaMixin:
         state, step = super()._on_restart(workload, state, step, rep)
         if self._simulating() and \
                 not getattr(workload, "self_replicating", False):
-            self.replica_state = copy_tree(state)
+            self.replica_state = self._copy(state)
         return state, step
 
 

@@ -28,6 +28,8 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Tuple, \
 
 import numpy as np
 
+from repro.obs import span
+
 
 def copy_tree(tree):
     """Deep device copy — replica state must own its buffers (jitted steps
@@ -96,19 +98,25 @@ class DecodeWorkload:
 
     def init_state(self):
         import jax.numpy as jnp
-        logits, cache = self.prefill(self.params, self.batch)
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        pos = jnp.full((tok.shape[0], 1), self.prompt_len, jnp.int32)
+        with span("repro.workload.prefill"):
+            logits, cache = self.prefill(self.params, self.batch)
+            tok = jnp.argmax(logits[:, -1, :],
+                             axis=-1)[:, None].astype(jnp.int32)
+            pos = jnp.full((tok.shape[0], 1), self.prompt_len, jnp.int32)
         return {"cache": cache, "tok": tok, "pos": pos, "out": []}
 
     def step(self, state, t):
         import jax.numpy as jnp
-        out = state["out"] + [np.asarray(state["tok"])]
-        logits, cache = self.decode(self.params, state["cache"],
-                                    state["tok"], state["pos"])
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        return {"cache": cache, "tok": tok, "pos": state["pos"] + 1,
-                "out": out}, None
+        with span("repro.workload.host_copy"):
+            out = state["out"] + [np.asarray(state["tok"])]
+        with span("repro.workload.decode"):
+            logits, cache = self.decode(self.params, state["cache"],
+                                        state["tok"], state["pos"])
+        with span("repro.workload.sample"):
+            tok = jnp.argmax(logits[:, -1, :],
+                             axis=-1)[:, None].astype(jnp.int32)
+            pos = state["pos"] + 1
+        return {"cache": cache, "tok": tok, "pos": pos, "out": out}, None
 
     @staticmethod
     def tokens(state) -> np.ndarray:

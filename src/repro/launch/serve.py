@@ -37,6 +37,7 @@ from repro.core.replica_map import ReplicaMap
 from repro.ft import DecodeWorkload, FTSession, StepKillInjector
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step_fns import make_decode_step, make_prefill_step
+from repro.obs import span
 
 
 class BatchFanout:
@@ -83,33 +84,34 @@ class BatchFanout:
     def fan_out(self, batch: np.ndarray) -> np.ndarray:
         """One bcast round; returns the batch as received by the serving
         computational worker."""
-        self.engine.begin_step()
-        step = self.fanouts
-        pend = {
-            w: self.engine.post(
-                ep,
-                ("bcast",
-                 batch if self.rmap.role_of(w)[1] == self.FRONTEND_RANK
-                 else None,
-                 self.FRONTEND_RANK),
-                step)
-            for w, ep in self.eps.items()}
-        got = {}
-        while len(got) < len(pend):
-            for w, ep in self.eps.items():
-                if w in got:
-                    continue
-                out = self.engine.resolve(ep, pend[w])
-                if out is not NOTHING:
-                    got[w] = out
-        cmp_w = self.rmap.cmp[self.SERVE_RANK]
-        rep_w = self.rmap.rep[self.SERVE_RANK]
-        if rep_w is not None:
-            np.testing.assert_array_equal(got[cmp_w], got[rep_w])
-        self.fanouts += 1
-        # priced fan-out traffic -> the clock's comm ledger (0.0 unpriced)
-        self.clock.charge_comm(self.transport)
-        return got[cmp_w]
+        with span("repro.serve.fanout"):
+            self.engine.begin_step()
+            step = self.fanouts
+            pend = {
+                w: self.engine.post(
+                    ep,
+                    ("bcast",
+                     batch if self.rmap.role_of(w)[1] == self.FRONTEND_RANK
+                     else None,
+                     self.FRONTEND_RANK),
+                    step)
+                for w, ep in self.eps.items()}
+            got = {}
+            while len(got) < len(pend):
+                for w, ep in self.eps.items():
+                    if w in got:
+                        continue
+                    out = self.engine.resolve(ep, pend[w])
+                    if out is not NOTHING:
+                        got[w] = out
+            cmp_w = self.rmap.cmp[self.SERVE_RANK]
+            rep_w = self.rmap.rep[self.SERVE_RANK]
+            if rep_w is not None:
+                np.testing.assert_array_equal(got[cmp_w], got[rep_w])
+            self.fanouts += 1
+            # priced fan-out traffic -> the clock's comm ledger (0.0 unpriced)
+            self.clock.charge_comm(self.transport)
+            return got[cmp_w]
 
 
 def serve_run_config(cfg: ModelConfig, *, batch: int,
@@ -154,6 +156,7 @@ class ReplicatedServer:
                                   obs=self.obs)
         self.failures = 0
         self.promotions = 0
+        self.batches = 0                 # generate calls, the spans' serial
         self.last_report = None
 
     def _extras(self, batch_tokens):
@@ -181,10 +184,11 @@ class ReplicatedServer:
         death is fatal (a restart would need a prefill replay)."""
         mode = "replication" if self.replication else "none"
         injector = StepKillInjector({kill_at: [0]}) if kill_at >= 0 else None
-        return FTSession(ft=FTConfig(mode=mode, topology=self.topology),
-                         injector=injector,
-                         n_logical_workers=1, workers_per_node=1,
-                         allow_restart=False, obs=self.obs)
+        with span("repro.serve.session"):
+            return FTSession(ft=FTConfig(mode=mode, topology=self.topology),
+                             injector=injector,
+                             n_logical_workers=1, workers_per_node=1,
+                             allow_restart=False, obs=self.obs)
 
     def generate(self, prompt_tokens: np.ndarray, n_gen: int,
                  kill_at: int = -1) -> np.ndarray:
@@ -192,22 +196,24 @@ class ReplicatedServer:
         generated tokens (replication failover or abort).  The batch
         reaches the serving rank over the transport bcast (logged,
         deduped), not by Python reference."""
-        session = self.session(kill_at)
-        comm0 = self.fanout.clock.breakdown.comm
-        prompt_tokens = self.fanout.fan_out(np.asarray(prompt_tokens))
-        try:
-            rep = session.run(self.workload(prompt_tokens), n_gen)
-        except RuntimeError:
-            # fatal (unrecoverable) kill: still record the failure
-            self.failures += 1
-            raise
-        # the batch fan-out's priced traffic lands in the same ledger as
-        # the run's own time (0.0 without a topology)
-        rep.time.comm += self.fanout.clock.breakdown.comm - comm0
-        self.last_report = rep
-        self.failures += rep.failures
-        self.promotions += rep.promotions
-        return DecodeWorkload.tokens(rep.final_state)
+        self.batches += 1
+        with span("repro.serve.generate", batch=self.batches):
+            session = self.session(kill_at)
+            comm0 = self.fanout.clock.breakdown.comm
+            prompt_tokens = self.fanout.fan_out(np.asarray(prompt_tokens))
+            try:
+                rep = session.run(self.workload(prompt_tokens), n_gen)
+            except RuntimeError:
+                # fatal (unrecoverable) kill: still record the failure
+                self.failures += 1
+                raise
+            # the batch fan-out's priced traffic lands in the same ledger as
+            # the run's own time (0.0 without a topology)
+            rep.time.comm += self.fanout.clock.breakdown.comm - comm0
+            self.last_report = rep
+            self.failures += rep.failures
+            self.promotions += rep.promotions
+            return DecodeWorkload.tokens(rep.final_state)
 
 
 def main(argv=None):
