@@ -15,7 +15,8 @@ and makes promotion O(1) and exact (the paper's FT theorem).
 
 Adapters:
   TrainWorkload   - jitted LM train step + deterministic batch cursor
-  DecodeWorkload  - greedy decode loop over (cache, tok, pos, out)
+  DecodeWorkload  - greedy decode loop over (cache, tok, pos, out); the
+                    jitted prefill and decode sample the token themselves
   SimAppWorkload  - a simrt generator app (HPCG / CloverLeaf / PIC) run by a
                     sequential in-process op resolver, whole-app state
 """
@@ -84,38 +85,34 @@ class DecodeWorkload:
     token, the position cursor and the emitted tokens. One step = append the
     current token and decode the next one. Replicating this state IS the
     paper's replication story for serving: the replica's cache stays current,
-    so failover is one promotion with no prefill replay."""
+    so failover is one promotion with no prefill replay.
+
+    ``prefill(params, batch) -> (tok, pos, cache)`` and ``decode(params,
+    cache, tok, pos) -> (tok, pos, cache)`` sample greedily inside their
+    jitted programs (``repro.launch.step_fns.greedy_prefill`` /
+    ``greedy_decode``), so a step dispatches one program and does no array
+    math of its own."""
 
     disk_checkpointable = False       # ``out`` grows; snapshot in memory
 
     def __init__(self, *, params, prefill: Callable, decode: Callable,
-                 batch: dict, prompt_len: int):
+                 batch: dict):
         self.params = params
         self.prefill = prefill
         self.decode = decode
         self.batch = batch
-        self.prompt_len = prompt_len
 
     def init_state(self):
-        import jax.numpy as jnp
         with span("repro.workload.prefill"):
-            logits, cache = self.prefill(self.params, self.batch)
-            tok = jnp.argmax(logits[:, -1, :],
-                             axis=-1)[:, None].astype(jnp.int32)
-            pos = jnp.full((tok.shape[0], 1), self.prompt_len, jnp.int32)
+            tok, pos, cache = self.prefill(self.params, self.batch)
         return {"cache": cache, "tok": tok, "pos": pos, "out": []}
 
     def step(self, state, t):
-        import jax.numpy as jnp
         with span("repro.workload.host_copy"):
             out = state["out"] + [np.asarray(state["tok"])]
         with span("repro.workload.decode"):
-            logits, cache = self.decode(self.params, state["cache"],
-                                        state["tok"], state["pos"])
-        with span("repro.workload.sample"):
-            tok = jnp.argmax(logits[:, -1, :],
-                             axis=-1)[:, None].astype(jnp.int32)
-            pos = state["pos"] + 1
+            tok, pos, cache = self.decode(self.params, state["cache"],
+                                          state["tok"], state["pos"])
         return {"cache": cache, "tok": tok, "pos": pos, "out": out}, None
 
     @staticmethod

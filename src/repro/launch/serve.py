@@ -36,7 +36,8 @@ from repro.core.coordinator import ClusterTopology
 from repro.core.replica_map import ReplicaMap
 from repro.ft import DecodeWorkload, FTSession, StepKillInjector
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.step_fns import make_decode_step, make_prefill_step
+from repro.launch.step_fns import (greedy_decode, greedy_prefill,
+                                   make_decode_step, make_prefill_step)
 from repro.obs import span
 
 
@@ -136,14 +137,14 @@ class ReplicatedServer:
             cfg = cfg.reduced()
         self.cfg = cfg
         run = serve_run_config(cfg, batch=batch, prompt_len=prompt_len)
-        self.prefill, self.model = make_prefill_step(run)
-        self.decode, _ = make_decode_step(run)
-        self.prefill = jax.jit(self.prefill)
-        self.decode = jax.jit(self.decode, donate_argnums=(1,))
+        prefill, self.model = make_prefill_step(run)
+        decode, _ = make_decode_step(run)
+        # greedy sampling inside the jitted programs: one dispatch a token
+        self.prefill = jax.jit(greedy_prefill(prefill))
+        self.decode = jax.jit(greedy_decode(decode), donate_argnums=(1,))
         self.params = self.model.init(jax.random.key(seed))
         self.replication = replication
         self.batch = batch
-        self.prompt_len = prompt_len
         self.topology = topology
         # one recorder shared by the fan-out transport and every serving
         # session (obs=True builds it; None keeps everything unwired)
@@ -175,8 +176,7 @@ class ReplicatedServer:
         """The decode loop as a Workload (also used by tests directly)."""
         return DecodeWorkload(params=self.params, prefill=self.prefill,
                               decode=self.decode,
-                              batch=self._extras(jnp.asarray(prompt_tokens)),
-                              prompt_len=self.prompt_len)
+                              batch=self._extras(jnp.asarray(prompt_tokens)))
 
     def session(self, kill_at: int = -1) -> FTSession:
         """One logical serving rank; replication adds its replica slice.

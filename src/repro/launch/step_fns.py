@@ -1,6 +1,11 @@
 """Step functions (train / prefill / decode) shared by the dry-run, the
 trainer and the server. Pure functions of explicit state — no globals — so
 they lower identically on every mesh.
+
+``make_prefill_step`` / ``make_decode_step`` return logits; the server
+wraps them with ``greedy_prefill`` / ``greedy_decode`` so that greedy
+sampling runs inside the same jitted program and a served token costs one
+dispatch.
 """
 from __future__ import annotations
 
@@ -52,3 +57,40 @@ def make_decode_step(run: RunConfig):
         return model.decode_step(params, cache, tokens, pos)
 
     return decode_step, model
+
+
+def greedy_token(logits):
+    """Greedy choice at the last position: [B, S, V] logits -> [B, 1] int32.
+
+    The barrier keeps the output projection out of the argmax's fusion.
+    Fused, the TPU compiler reads the projection's float32 accumulators
+    rather than the bf16 logits the model returns, so near ties the
+    tokens part from those of the logits step."""
+    logits = jax.lax.optimization_barrier(logits)
+    return jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
+
+
+def greedy_prefill(prefill):
+    """``prefill(params, batch) -> (logits, cache)`` as a step that returns
+    ``(tok, pos, cache)``: the first token and the next position (the
+    prompt's length).  Named ``prefill_step`` so the jitted program keeps
+    its name (``jit_prefill_step``)."""
+    def prefill_step(params, batch):
+        logits, cache = prefill(params, batch)
+        tok = greedy_token(logits)
+        pos = jnp.full((tok.shape[0], 1), batch["tokens"].shape[1],
+                       jnp.int32)
+        return tok, pos, cache
+
+    return prefill_step
+
+
+def greedy_decode(decode):
+    """``decode(params, cache, tokens, pos) -> (logits, cache)`` as a step
+    that returns ``(tok, pos + 1, cache)``.  Named ``decode_step`` so the
+    jitted program keeps its name (``jit_decode_step``)."""
+    def decode_step(params, cache, tokens, pos):
+        logits, cache = decode(params, cache, tokens, pos)
+        return greedy_token(logits), pos + 1, cache
+
+    return decode_step

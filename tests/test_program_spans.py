@@ -57,7 +57,6 @@ def test_every_span_of_the_serving_path_with_its_count(traced_batch):
         "repro.workload.prefill": 1,
         "repro.workload.host_copy": N_GEN + KILL_AT,
         "repro.workload.decode": N_GEN + KILL_AT,
-        "repro.workload.sample": N_GEN + KILL_AT,
     }
     (_, _, stats), = traced_batch["repro.serve.generate"]
     assert stats == {"batch": 2}
@@ -79,7 +78,6 @@ def _inside(inner, outer):
     ("repro.ft.replica_step", "repro.ft.step"),
     ("repro.workload.host_copy", "repro.ft.step"),
     ("repro.workload.decode", "repro.ft.step"),
-    ("repro.workload.sample", "repro.ft.step"),
 ])
 def test_spans_nest_by_layer(traced_batch, child, parent):
     assert all(_inside(c, traced_batch[parent]) for c in traced_batch[child])

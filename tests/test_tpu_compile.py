@@ -11,6 +11,7 @@ worker imports this file.  Keep all such compiles in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_chunk_scan
 from repro.kernels.rmsnorm import rmsnorm
 from repro.launch.serve import serve_run_config
-from repro.launch.step_fns import (make_decode_step, make_prefill_step,
+from repro.launch.step_fns import (greedy_decode, greedy_prefill,
+                                   make_decode_step, make_prefill_step,
                                    make_train_step)
 from repro.launch.train import train_run_config
 from repro.models import api as model_api
@@ -121,11 +123,11 @@ def test_xlstm_train_step_fits_v5e(one_chip):
         < HBM_BYTES
 
 
-@pytest.fixture(scope="module")
-def xlstm_serve():
-    """The chip smoke's serving shapes: batch 8, prompt 1024."""
-    cfg = get_arch(XLSTM)
-    run = serve_run_config(cfg, batch=8, prompt_len=1024)
+@functools.cache
+def _serve(arch, batch, prompt_len):
+    """Abstract prefill and decode arguments of one server shape."""
+    cfg = get_arch(arch)
+    run = serve_run_config(cfg, batch=batch, prompt_len=prompt_len)
     prefill, model = make_prefill_step(run)
     decode, _ = make_decode_step(run)
     params = jax.eval_shape(model.init, jax.random.key(0))
@@ -133,19 +135,118 @@ def xlstm_serve():
     return prefill, decode, params, batch
 
 
-def test_xlstm_prefill_compiles_for_v5e(one_chip, xlstm_serve):
-    prefill, _, params, batch = xlstm_serve
-    compiled = jax.jit(prefill).lower(*_on(one_chip, (params, batch))) \
-        .compile()
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+# the chip smoke's xlstm serving shapes and the whisper serving cell's
+SERVE_SHAPES = {XLSTM: (8, 1024), "whisper-tiny": (16, 4)}
 
 
-def test_xlstm_decode_compiles_for_v5e(one_chip, xlstm_serve):
-    prefill, decode, params, batch = xlstm_serve
-    _, cache = jax.eval_shape(prefill, params, batch)
-    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        *_on(one_chip, (params, cache, tok, tok))).compile()
+def _compile_step(one_chip, arch, step, greedy=False):
+    """Compile the prefill or decode of ``arch`` for one v5e chip, the
+    logits step or the served one with greedy sampling folded in; the
+    step's arguments and temporaries must fit the chip's HBM."""
+    b, prompt_len = SERVE_SHAPES[arch]
+    prefill, decode, params, batch = _serve(arch, b, prompt_len)
+    if step == "prefill":
+        fn, args, donate = prefill, (params, batch), ()
+        wrap = greedy_prefill
+    else:
+        _, cache = jax.eval_shape(prefill, params, batch)
+        tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+        fn, args, donate = decode, (params, cache, tok, tok), (1,)
+        wrap = greedy_decode
+    fn = wrap(fn) if greedy else fn
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_on(one_chip, args)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    return compiled
+
+
+def test_xlstm_prefill_compiles_for_v5e(one_chip):
+    _compile_step(one_chip, XLSTM, "prefill")
+
+
+def test_xlstm_decode_compiles_for_v5e(one_chip):
+    _compile_step(one_chip, XLSTM, "decode")
+
+
+# ------------------------------------------------- argmax apart from the
+# output projection
+
+def _computations(hlo: str) -> dict:
+    """HLO module text -> {computation name: its instruction lines}."""
+    comps, body = {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            body = comps.setdefault(
+                line.removeprefix("ENTRY ").split()[0].lstrip("%"), [])
+        elif line == "}":
+            body = None
+        elif body is not None:
+            body.append(line)
+    return comps
+
+
+def _is_argmax(line: str) -> bool:
+    """A variadic reduce to a (value, s32 index) pair: ``argmax``."""
+    m = re.search(r"= (\(.*?) reduce\(", line)
+    return bool(m) and "s32[" in m.group(1)
+
+
+def _argmax_fusions(hlo: str) -> dict:
+    """{fused computation holding an argmax: whether it, or a fusion nested
+    in it, also computes a convolution}."""
+    comps = _computations(hlo)
+    fused = {c for lines in comps.values() for line in lines
+             if " fusion(" in line
+             for c in re.findall(r"calls=%([\w.\-]+)", line)}
+
+    def has_conv(name, seen):
+        seen.add(name)
+        lines = comps.get(name, [])
+        return any(" convolution(" in line for line in lines) or any(
+            has_conv(c, seen) for line in lines
+            for c in re.findall(r"calls=%([\w.\-]+)", line)
+            if c not in seen)
+
+    return {name: has_conv(name, set()) for name in fused
+            if any(map(_is_argmax, comps[name]))}
+
+
+def test_argmax_fusion_finder_sees_a_fused_projection():
+    """The finder on the form the v5e compiler gives when the output
+    projection is fused into the argmax (and on the barrier's form)."""
+    fused = """%region_0.1 (a: bf16[], b: s32[], c: bf16[], d: s32[]) -> (bf16[], s32[]) {
+  ROOT %tuple.1 = (bf16[], s32[]) tuple(%a, %b)
+}
+
+%fused_computation.1 (param_0: bf16[16,384], param_1: bf16[384,51865]) -> (bf16[16]{0:T(256)(128)(2,1)}, s32[16]{0:T(128)}) {
+  %convolution.39 = bf16[16,51865]{1,0:T(8,128)(2,1)} convolution(%param_0, %param_1), dim_labels=bf_io->bf
+  %iota.22 = s32[16,51865]{1,0} iota(), iota_dimension=1
+  ROOT %reduce.64 = (bf16[16]{0:T(256)(128)(2,1)}, s32[16]{0:T(128)}) reduce(%convolution.39, %iota.22, %c0, %c1), dimensions={1}, to_apply=%region_0.1
+}
+
+ENTRY %main.2 (p0: bf16[16,384], p1: bf16[384,51865]) -> s32[16] {
+  %fusion.1 = (bf16[16]{0}, s32[16]{0}) fusion(%p0, %p1), kind=kOutput, calls=%fused_computation.1
+  ROOT %gte = s32[16]{0} get-tuple-element(%fusion.1), index=1
+}
+"""
+    assert _argmax_fusions(fused) == {"fused_computation.1": True}
+    apart = fused.replace(
+        "%convolution.39 = bf16[16,51865]{1,0:T(8,128)(2,1)} convolution("
+        "%param_0, %param_1), dim_labels=bf_io->bf",
+        "%convolution.39 = bf16[16,51865]{1,0} copy(%param_0)")
+    assert _argmax_fusions(apart) == {"fused_computation.1": False}
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+@pytest.mark.parametrize("arch", ["whisper-tiny", XLSTM])
+def test_served_steps_keep_the_projection_out_of_the_argmax(one_chip, arch,
+                                                            step):
+    """The served steps compile for v5e, and their argmax reads the bf16
+    logits the logits step returns: no fusion computes both the output
+    projection and the argmax, which would let the compiler choose the
+    token from the projection computed another way."""
+    compiled = _compile_step(one_chip, arch, step, greedy=True)
+    found = _argmax_fusions(compiled.as_text())
+    assert found, "no argmax fusion in the compiled step"
+    assert not any(found.values()), found
