@@ -26,6 +26,7 @@ from repro.configs.whisper_tiny import CONFIG as WHISPER_TINY
 from repro.configs.xlstm_350m import CONFIG as XLSTM_350M
 from repro.configs.llama_3_2_vision_11b import CONFIG as LLAMA_3_2_VISION_11B
 from repro.configs.zamba2_7b import CONFIG as ZAMBA2_7B
+from repro.configs.zamba2_7b import CUT as ZAMBA2_7B_18L
 
 ARCHS: dict[str, ModelConfig] = {
     c.name: c
@@ -40,6 +41,7 @@ ARCHS: dict[str, ModelConfig] = {
         XLSTM_350M,
         LLAMA_3_2_VISION_11B,
         ZAMBA2_7B,
+        ZAMBA2_7B_18L,
     )
 }
 

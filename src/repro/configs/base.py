@@ -12,6 +12,11 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 
+# decoded tokens a prefill's KV cache has room for when its caller does not
+# say how many it will decode (the server always says)
+DEFAULT_NEW_TOKENS = 64
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for every supported family.
@@ -51,10 +56,18 @@ class ModelConfig:
     # --- SSM / hybrid --------------------------------------------------------
     ssm_state: int = 0                # mamba2 state dim (zamba2: 64)
     ssm_chunk: int = 128              # mamba2 chunked-scan chunk length
-    attn_every: int = 0               # hybrid: shared attn block cadence
+    ssm_groups: int = 1               # mamba2 B/C groups (zamba2: 2)
+    ssm_dt_min: float = 0.0           # mamba2 floor on the time step
     slstm_every: int = 0              # xlstm: every k-th block is sLSTM
     conv_kernel: int = 4              # mamba2 depthwise conv width
     expand: int = 2                   # mamba2 expansion factor
+    # hybrid (Zamba2): one character a layer, "m" a Mamba2 layer, "h" a
+    # shared block applied before that layer's Mamba2 (layers_block_type)
+    block_pattern: str = ""
+    n_shared_blocks: int = 0          # shared blocks, applied in turn
+    adapter_rank: int = 0             # per-application LoRA on the MLP
+    attn_in: int = 0                  # attention input width (0: d_model)
+    attn_scale: float = 0.0           # softmax scale (0: head_dim ** -0.5)
 
     # --- encoder-decoder (audio) ---------------------------------------------
     is_encoder_decoder: bool = False
@@ -73,15 +86,19 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def hybrid_layers(self) -> tuple:
+        """Layers with a shared-block application before their Mamba2."""
+        return tuple(i for i, c in enumerate(self.block_pattern) if c == "h")
+
+    @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
     @property
     def is_subquadratic(self) -> bool:
-        """Can this arch serve a 500k-token context (long_500k shape)?"""
-        if self.family in ("ssm", "hybrid"):
-            return True
-        return self.sliding_window > 0
+        """Can this arch serve a 500k-token context (long_500k shape)?
+        A hybrid's shared attention is full attention, as published."""
+        return self.family == "ssm" or self.sliding_window > 0
 
     @property
     def supports_decode(self) -> bool:
@@ -99,7 +116,7 @@ class ModelConfig:
     def reduced(self) -> "ModelConfig":
         """A tiny same-family config for CPU smoke tests."""
         small = dict(
-            n_layers=min(self.n_layers, 4 if self.attn_every == 0 else 2 * max(1, self.attn_every)),
+            n_layers=min(self.n_layers, 4),
             d_model=128,
             n_heads=4,
             n_kv_heads=max(1, min(4, 4 * self.n_kv_heads // max(self.n_heads, 1))),
@@ -111,17 +128,29 @@ class ModelConfig:
             sliding_window=64 if self.sliding_window else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_chunk=16,
-            attn_every=min(self.attn_every, 3) if self.attn_every else 0,
             slstm_every=min(self.slstm_every, 2) if self.slstm_every else 0,
             n_encoder_layers=min(self.n_encoder_layers, 2),
             n_frames=32 if self.is_encoder_decoder else self.n_frames,
             cross_attn_every=min(self.cross_attn_every, 2) if self.cross_attn_every else 0,
             n_image_tokens=16 if self.n_image_tokens else 0,
         )
-        if self.attn_every:
-            # hybrid: keep a small multiple of the attention cadence
-            small["n_layers"] = 2 * small["attn_every"] + 1
+        if self.block_pattern:
+            # hybrid: three applications over six layers, so both shared
+            # blocks are present and the first is applied twice; attention
+            # reads the 2d-wide concatenation with Zamba2's scale
+            small.update(n_layers=6, block_pattern="mhmhmh",
+                         adapter_rank=min(self.adapter_rank, 8),
+                         attn_in=2 * small["d_model"], head_dim=64,
+                         attn_scale=32 ** -0.5)
         return dataclasses.replace(self, **small)
+
+    def first_layers(self, n: int) -> "ModelConfig":
+        """The chip's share of a pipeline: layers ``0..n-1`` at every
+        published width (a stage that holds whole layers)."""
+        assert 0 < n <= self.n_layers
+        return dataclasses.replace(
+            self, name=f"{self.name}-{n}l", n_layers=n,
+            block_pattern=self.block_pattern[:n])
 
 
 @dataclass(frozen=True)

@@ -48,7 +48,7 @@ _PARAM_RULES = {
     # mamba2
     "in_z":     ((None, "model")),
     "in_x":     ((None, "model")),
-    "in_b":     ((None, None)),
+    "in_b":     ((None, None)),         # [d, groups * state]: small
     "in_c":     ((None, None)),
     "in_dt":    ((None, "model")),
     "conv_w":   ((None, None)),
@@ -57,6 +57,13 @@ _PARAM_RULES = {
     "d_skip":   ((None,)),
     "dt_bias":  ((None,)),
     "out_proj": (("model", None)),
+    # zamba2 shared blocks (q/k/v read the 2d-wide concatenation: the
+    # attention rules above) and per-application LoRA and linear
+    "gate_up":  ((None, "model")),
+    "down_proj": (("model", None)),
+    "lora_a":   ((None, None)),
+    "lora_b":   ((None, "model")),
+    "linear":   ((None, "model")),
     # norms
     "scale":    ((None,)),
 }

@@ -26,7 +26,7 @@ from typing import Any, Optional, Tuple
 
 from repro.configs.base import FTConfig
 from repro.core import ckpt_policy
-from repro.ft.workload import copy_tree
+from repro.ft.workload import copy_tree, state_bytes
 from repro.obs import span
 
 
@@ -113,8 +113,11 @@ class _ReplicaMixin:
 
     @staticmethod
     def _copy(state):
-        """The replica slice's own copy of ``state``."""
-        with span("repro.ft.replica_copy"):
+        """The replica slice's own copy of ``state``; the span carries the
+        bytes of the KV caches and of the recurrent state it copies."""
+        size = state_bytes(state)
+        with span("repro.ft.replica_copy", kv_bytes=size["kv"],
+                  ssm_bytes=size["ssm"]):
             return copy_tree(state)
 
     def on_start(self, workload, state, rep) -> None:

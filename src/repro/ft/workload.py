@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Tuple, \
 
 import numpy as np
 
+from repro.configs.base import DEFAULT_NEW_TOKENS
 from repro.obs import span
 
 
@@ -41,6 +42,29 @@ def copy_tree(tree):
     except ImportError:
         return copy.deepcopy(tree)
     return jax.tree.map(lambda x: x.copy() if hasattr(x, "copy") else x, tree)
+
+
+def state_bytes(state) -> dict:
+    """Bytes of a decode state's two kinds of cache: ``kv`` the KV caches
+    (every dict that holds ``k`` and ``v``), ``ssm`` the rest of
+    ``state["cache"]``, the recurrent state.  Zero for any other state."""
+    out = {"kv": 0, "ssm": 0}
+
+    def walk(node, kind):
+        if isinstance(node, dict):
+            if "k" in node and "v" in node:
+                kind = "kv"
+            for child in node.values():
+                walk(child, kind)
+        elif isinstance(node, (tuple, list)):
+            for child in node:
+                walk(child, kind)
+        else:
+            out[kind] += int(getattr(node, "nbytes", 0))
+
+    if isinstance(state, dict) and "cache" in state:
+        walk(state["cache"], "ssm")
+    return out
 
 
 @runtime_checkable
@@ -87,24 +111,27 @@ class DecodeWorkload:
     paper's replication story for serving: the replica's cache stays current,
     so failover is one promotion with no prefill replay.
 
-    ``prefill(params, batch) -> (tok, pos, cache)`` and ``decode(params,
-    cache, tok, pos) -> (tok, pos, cache)`` sample greedily inside their
-    jitted programs (``repro.launch.step_fns.greedy_prefill`` /
+    ``prefill(params, batch, n_gen) -> (tok, pos, cache)`` and
+    ``decode(params, cache, tok, pos) -> (tok, pos, cache)`` sample greedily
+    inside their jitted programs (``repro.launch.step_fns.greedy_prefill`` /
     ``greedy_decode``), so a step dispatches one program and does no array
-    math of its own."""
+    math of its own.  ``n_gen`` is the number of steps the run takes: the
+    prefill's KV caches hold the prompt and every decoded token."""
 
     disk_checkpointable = False       # ``out`` grows; snapshot in memory
 
     def __init__(self, *, params, prefill: Callable, decode: Callable,
-                 batch: dict):
+                 batch: dict, n_gen: int = DEFAULT_NEW_TOKENS):
         self.params = params
         self.prefill = prefill
         self.decode = decode
         self.batch = batch
+        self.n_gen = n_gen
 
     def init_state(self):
         with span("repro.workload.prefill"):
-            tok, pos, cache = self.prefill(self.params, self.batch)
+            tok, pos, cache = self.prefill(self.params, self.batch,
+                                           self.n_gen)
         return {"cache": cache, "tok": tok, "pos": pos, "out": []}
 
     def step(self, state, t):

@@ -35,6 +35,7 @@ from repro.configs.base import FTConfig, ModelConfig, ShapeConfig
 from repro.core.coordinator import ClusterTopology
 from repro.core.replica_map import ReplicaMap
 from repro.ft import DecodeWorkload, FTSession, StepKillInjector
+from repro.ft.workload import state_bytes
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step_fns import (greedy_decode, greedy_prefill,
                                    make_decode_step, make_prefill_step)
@@ -139,8 +140,10 @@ class ReplicatedServer:
         run = serve_run_config(cfg, batch=batch, prompt_len=prompt_len)
         prefill, self.model = make_prefill_step(run)
         decode, _ = make_decode_step(run)
-        # greedy sampling inside the jitted programs: one dispatch a token
-        self.prefill = jax.jit(greedy_prefill(prefill))
+        # greedy sampling inside the jitted programs: one dispatch a token;
+        # the prefill compiles once for each number of tokens to decode,
+        # which sizes its KV caches
+        self.prefill = jax.jit(greedy_prefill(prefill), static_argnums=(2,))
         self.decode = jax.jit(greedy_decode(decode), donate_argnums=(1,))
         self.params = self.model.init(jax.random.key(seed))
         self.replication = replication
@@ -201,8 +204,10 @@ class ReplicatedServer:
             session = self.session(kill_at)
             comm0 = self.fanout.clock.breakdown.comm
             prompt_tokens = self.fanout.fan_out(np.asarray(prompt_tokens))
+            wl = self.workload(prompt_tokens)
+            wl.n_gen = n_gen             # the KV caches hold every token
             try:
-                rep = session.run(self.workload(prompt_tokens), n_gen)
+                rep = session.run(wl, n_gen)
             except RuntimeError:
                 # fatal (unrecoverable) kill: still record the failure
                 self.failures += 1
@@ -210,10 +215,17 @@ class ReplicatedServer:
             # the batch fan-out's priced traffic lands in the same ledger as
             # the run's own time (0.0 without a topology)
             rep.time.comm += self.fanout.clock.breakdown.comm - comm0
+            if self.obs is not None:
+                for kind, n in state_bytes(rep.final_state).items():
+                    self.obs.metrics.set_gauge(f"serve.state_bytes.{kind}", n)
+            tokens = DecodeWorkload.tokens(rep.final_state)
+            # the report kept drops the decode state, so that a batch's KV
+            # caches are freed before the next batch's prefill
+            rep.final_state = None
             self.last_report = rep
             self.failures += rep.failures
             self.promotions += rep.promotions
-            return DecodeWorkload.tokens(rep.final_state)
+            return tokens
 
 
 def main(argv=None):

@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, RunConfig
+from repro.configs.base import DEFAULT_NEW_TOKENS, ModelConfig, RunConfig
 from repro.models import api as model_api
 from repro.optim import adamw
 
@@ -44,8 +44,8 @@ def make_train_step(run: RunConfig):
 def make_prefill_step(run: RunConfig):
     model = make_model(run)
 
-    def prefill_step(params, batch):
-        return model.prefill(params, batch)
+    def prefill_step(params, batch, n_gen=DEFAULT_NEW_TOKENS):
+        return model.prefill(params, batch, n_gen)
 
     return prefill_step, model
 
@@ -71,12 +71,13 @@ def greedy_token(logits):
 
 
 def greedy_prefill(prefill):
-    """``prefill(params, batch) -> (logits, cache)`` as a step that returns
-    ``(tok, pos, cache)``: the first token and the next position (the
-    prompt's length).  Named ``prefill_step`` so the jitted program keeps
-    its name (``jit_prefill_step``)."""
-    def prefill_step(params, batch):
-        logits, cache = prefill(params, batch)
+    """``prefill(params, batch, n_gen) -> (logits, cache)`` as a step that
+    returns ``(tok, pos, cache)``: the first token and the next position
+    (the prompt's length); the cache has room for ``n_gen`` decoded tokens.
+    Named ``prefill_step`` so the jitted program keeps its name
+    (``jit_prefill_step``)."""
+    def prefill_step(params, batch, n_gen=DEFAULT_NEW_TOKENS):
+        logits, cache = prefill(params, batch, n_gen)
         tok = greedy_token(logits)
         pos = jnp.full((tok.shape[0], 1), batch["tokens"].shape[1],
                        jnp.int32)

@@ -3,7 +3,8 @@
 ``build_model(cfg)`` returns an object exposing:
   init(rng) / init_abstract()
   loss_fn(params, batch)                      -- train shapes
-  prefill(params, batch) -> (logits, cache)   -- prefill shapes
+  prefill(params, batch, n_gen) -> (logits, cache)  -- prefill shapes;
+                                              the KV caches hold n_gen more
   decode_step(params, cache, tokens, pos)     -- decode shapes
   init_cache(batch, seq_len)
 

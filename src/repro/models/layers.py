@@ -80,10 +80,12 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
 NEG_INF = -1e30
 
 
-def _scores_block(q, k, q_pos, k_pos, window, causal: bool = True):
-    """q: [B, Tq, Hkv, G, Dh], k: [B, Tk, Hkv, Dh] -> masked f32 scores."""
+def _scores_block(q, k, q_pos, k_pos, window, causal: bool = True,
+                  scale: Optional[float] = None):
+    """q: [B, Tq, Hkv, G, Dh], k: [B, Tk, Hkv, Dh] -> masked f32 scores,
+    scaled by ``scale`` (default head_dim ** -0.5)."""
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(F32), k.astype(F32))
-    s = s * (1.0 / q.shape[-1] ** 0.5)
+    s = s * (scale or 1.0 / q.shape[-1] ** 0.5)
     mask = (k_pos >= 0)[:, None, :]                           # empty cache slots
     if causal:
         mask &= k_pos[:, None, :] <= q_pos[:, :, None]
@@ -122,6 +124,7 @@ def blockwise_attention(
     kv_block: int = 512,
     q_block: int = 512,
     causal: bool = True,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Causal (optionally sliding-window) attention, O(Sq*w) for SWA."""
     b, sq, hq, dh = q.shape
@@ -154,7 +157,8 @@ def blockwise_attention(
             k_t = lax.dynamic_slice_in_dim(k, start, kv_block, axis=1)
             v_t = lax.dynamic_slice_in_dim(v, start, kv_block, axis=1)
             kp_t = lax.dynamic_slice_in_dim(k_pos, start, kv_block, axis=1)
-            s = _scores_block(q_tile, k_t, qp_tile, kp_t, window, causal)
+            s = _scores_block(q_tile, k_t, qp_tile, kp_t, window, causal,
+                              scale)
             return _online_update(carry, s, v_t), None
 
         (m, l, acc), _ = lax.scan(body, (m0, l0, a0), jnp.arange(n_tiles))
@@ -190,7 +194,8 @@ def blockwise_attention(
     return out.astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, window: int = 0):
+def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, window: int = 0,
+                     scale: Optional[float] = None):
     """Single-token attention against a (possibly ring-buffered) KV cache.
 
     q: [B, 1, Hq, Dh]; caches: [B, S, Hkv, Dh]; k_pos: [B, S] absolute
@@ -200,7 +205,8 @@ def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, window: int = 0):
     hkv = k_cache.shape[2]
     g = hq // hkv
     qg = q.reshape(b, 1, hkv, g, dh)
-    s = _scores_block(qg, k_cache, q_pos, k_pos, window)   # [B,Hkv,G,1,S]
+    s = _scores_block(qg, k_cache, q_pos, k_pos, window,
+                      scale=scale)                      # [B,Hkv,G,1,S]
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = p.sum(axis=-1)
@@ -214,13 +220,16 @@ def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, window: int = 0):
 # ---------------------------------------------------------------------------
 
 def attention_params(cfg: ModelConfig, rng, dtype, d_model: int = 0) -> Params:
+    """q/k/v read ``cfg.attn_in`` features where it is set (Zamba2's
+    concatenated input), ``d_model`` otherwise; the output is d_model."""
     d = d_model or cfg.d_model
+    d_in = cfg.attn_in or d
     dh = cfg.resolved_head_dim
     r = split_rngs(rng, 4)
     p = {
-        "wq": _dense_init(r[0], (d, cfg.n_heads, dh), dtype),
-        "wk": _dense_init(r[1], (d, cfg.n_kv_heads, dh), dtype),
-        "wv": _dense_init(r[2], (d, cfg.n_kv_heads, dh), dtype),
+        "wq": _dense_init(r[0], (d_in, cfg.n_heads, dh), dtype),
+        "wk": _dense_init(r[1], (d_in, cfg.n_kv_heads, dh), dtype),
+        "wv": _dense_init(r[2], (d_in, cfg.n_kv_heads, dh), dtype),
         "wo": _dense_init(r[3], (cfg.n_heads, dh, d), dtype),
     }
     if cfg.qkv_bias:
@@ -257,20 +266,26 @@ def attention_apply(
     positions: jnp.ndarray,
     *,
     cache: Optional[dict] = None,
+    n_gen: Optional[int] = None,
     kv_block: int = 512,
     use_rope: bool = True,
     window: Optional[int] = None,
 ):
-    """Returns (y, new_cache). cache=None => prefill/train without cache reuse."""
+    """Returns (y, new_cache).  With ``cache`` one token is decoded against
+    it; otherwise the whole sequence attends causally, and with ``n_gen``
+    the prefill emits a cache with room for ``n_gen`` decoded tokens.
+    The softmax scale is ``cfg.attn_scale`` where set."""
     theta = cfg.rope_theta if use_rope else 0.0
     win = cfg.sliding_window if window is None else window
+    scale = cfg.attn_scale or None
     q, k, v = _project_qkv(cfg, p, x, positions, theta)
 
     if cache is None:
         out = blockwise_attention(q, k, v, positions, positions,
-                                  window=win, kv_block=kv_block)
-        new_cache = None
-    elif x.shape[1] == 1:
+                                  window=win, kv_block=kv_block, scale=scale)
+        new_cache = None if n_gen is None else \
+            init_cache_from(cfg, k, v, positions, win, n_gen)
+    else:
         # decode: write into ring buffer, attend against the cache
         slot = (cache["idx"] % cache["k"].shape[1]).astype(jnp.int32)
         k_cache = _ring_write(cache["k"], k, slot)
@@ -278,14 +293,10 @@ def attention_apply(
         k_pos = lax.dynamic_update_slice(
             cache["pos"], positions.astype(cache["pos"].dtype)[:, :1],
             (0, slot))
-        out = decode_attention(q, k_cache, v_cache, positions, k_pos, window=win)
+        out = decode_attention(q, k_cache, v_cache, positions, k_pos,
+                               window=win, scale=scale)
         new_cache = {"k": k_cache, "v": v_cache, "pos": k_pos,
                      "idx": cache["idx"] + 1}
-    else:
-        # prefill with cache emission
-        out = blockwise_attention(q, k, v, positions, positions,
-                                  window=win, kv_block=kv_block)
-        new_cache = init_cache_from(cfg, k, v, positions, win)
 
     y = jnp.einsum("bshe,hed->bsd", out, p["wo"])
     if cfg.attn_out_bias and "bo" in p:
@@ -300,27 +311,27 @@ def _ring_write(cache, val, slot):
 
 
 def init_cache_from(cfg: ModelConfig, k, v, positions, window: int,
-                    headroom: int = 64):
-    """Build a cache from prefill keys/values.
+                    n_gen: int):
+    """Build a cache from prefill keys/values with room for ``n_gen``
+    decoded tokens.
 
-    Sliding-window archs get a ring buffer of exactly ``window`` slots (the
-    Mistral rolling buffer). Full-attention archs get ``headroom`` spare
-    slots so decode appends instead of ring-overwriting history (decode
-    writes at slot idx %% capacity, starting at idx = prompt_len)."""
-    b, s = k.shape[:2]
-    if window:
-        cap = min(s, window)
-        k_c = k[:, s - cap:, :, :]
-        v_c = v[:, s - cap:, :, :]
-        pos_c = positions[:, s - cap:].astype(jnp.int32)
-    else:
-        pad = [(0, 0), (0, headroom), (0, 0), (0, 0)]
-        k_c = jnp.pad(k, pad)
-        v_c = jnp.pad(v, pad)
-        pos_c = jnp.pad(positions.astype(jnp.int32), [(0, 0), (0, headroom)],
-                        constant_values=-1)
-    return {"k": k_c, "v": v_c, "pos": pos_c,
-            "idx": jnp.asarray(s, jnp.int32)}
+    Decode writes position p at slot p %% capacity (idx counts from the
+    prompt's length).  Full-attention archs hold prompt + ``n_gen`` slots,
+    so no decoded key overwrites another.  Sliding-window archs hold
+    min(prompt + n_gen, window) slots (the Mistral rolling buffer), each
+    key at the slot of its position, so a ring write always replaces the
+    oldest key."""
+    s = k.shape[1]
+    cap = min(s + n_gen, window) if window else s + n_gen
+    pos = positions.astype(jnp.int32)
+    if cap > s:
+        pad = [(0, 0), (0, cap - s), (0, 0), (0, 0)]
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+        pos = jnp.pad(pos, pad[:2], constant_values=-1)
+    elif cap < s or s % cap:
+        k, v, pos = (jnp.roll(a[:, s - cap:], s % cap, axis=1)
+                     for a in (k, v, pos))
+    return {"k": k, "v": v, "pos": pos, "idx": jnp.asarray(s, jnp.int32)}
 
 
 def empty_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype,

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import DEFAULT_NEW_TOKENS, ModelConfig
 from repro.models import layers as L
 from repro.models import moe as MOE
 
@@ -179,8 +179,10 @@ class Transformer:
             lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape).copy(),
             cache)
 
-    def prefill(self, params: Params, batch: dict):
-        """Process the full prompt; return (last_logits, cache)."""
+    def prefill(self, params: Params, batch: dict,
+                n_gen: int = DEFAULT_NEW_TOKENS):
+        """Process the full prompt; return (last_logits, cache) with room
+        for ``n_gen`` decoded tokens."""
         cfg = self.cfg
         tokens = batch["tokens"]
         b, s = tokens.shape
@@ -206,7 +208,7 @@ class Transformer:
                     xi = xi + _ffn_apply(cfg, lpi["ffn"],
                                          L.rmsnorm(lpi["ln2"], xi, cfg.norm_eps))
                     return xi, L.init_cache_from(cfg, k, v, positions,
-                                                 cfg.sliding_window)
+                                                 cfg.sliding_window, n_gen)
                 xc, caches = lax.scan(self._maybe_remat(self_layer2), xc, lp)
                 return xc, (caches, kv)
             x, (self_caches, cross_kvs) = lax.scan(
@@ -226,7 +228,7 @@ class Transformer:
                 xc = xc + _ffn_apply(cfg, lp["ffn"],
                                      L.rmsnorm(lp["ln2"], xc, cfg.norm_eps))
                 return xc, L.init_cache_from(cfg, k, v, positions,
-                                             cfg.sliding_window)
+                                             cfg.sliding_window, n_gen)
             x, cache = lax.scan(self._maybe_remat(body), x, params["layers"])
 
         x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)

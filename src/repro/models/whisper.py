@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import DEFAULT_NEW_TOKENS, ModelConfig
 from repro.models import layers as L
 
 F32 = jnp.float32
@@ -138,7 +138,8 @@ class Whisper:
         return L.chunked_lm_loss(cfg, params["embed"], x, labels,
                                  self.seq_chunk)
 
-    def prefill(self, params: Params, batch: dict):
+    def prefill(self, params: Params, batch: dict,
+                n_gen: int = DEFAULT_NEW_TOKENS):
         cfg = self.cfg
         tokens = batch["tokens"]
         memory = self.encode(params, batch["frames"])
@@ -158,7 +159,7 @@ class Whisper:
                 kv=kv, gated=False)
             xc = xc + _gelu_mlp(lp["mlp"],
                                 L.rmsnorm(lp["ln2"], xc, cfg.norm_eps))
-            self_cache = L.init_cache_from(cfg, k, v, pos, 0)
+            self_cache = L.init_cache_from(cfg, k, v, pos, 0, n_gen)
             return xc, (self_cache, kv)
 
         x, (self_cache, cross_kv) = lax.scan(self._maybe_remat(body), x,

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import DEFAULT_NEW_TOKENS, ModelConfig
 from repro.models import layers as L
 
 F32 = jnp.float32
@@ -299,7 +299,8 @@ class XLSTM:
                       "m": jnp.full((g, batch, h, dh), -30.0, F32)},
         }
 
-    def prefill(self, params, batch):
+    def prefill(self, params, batch, n_gen: int = DEFAULT_NEW_TOKENS):
+        """The recurrent state is of fixed size: ``n_gen`` sizes nothing."""
         cfg = self.cfg
         tokens = batch["tokens"]
         x = L.embed_lookup(params["embed"], tokens)

@@ -1,14 +1,24 @@
-"""Zamba2-style hybrid: Mamba2 backbone + ONE shared-weight attention block.
+"""Zamba2 hybrid: a Mamba2 backbone with shared attention blocks applied in
+turn, as ``transformers``' ``modeling_zamba2`` defines it.
 
-Layer layout for n_layers=81, attn_every=6:
-  13 groups of [shared attention, 6 mamba blocks] + 3 tail mamba blocks.
-The attention block's *weights* are shared across all applications (Zamba2's
-parameter-sharing trick) but each application has its own KV cache at serve
-time. The shared attention runs sliding-window at long context, which keeps
-the arch sub-quadratic end to end (long_500k applicable).
+``cfg.block_pattern`` has one character a layer: ``m`` a Mamba2 layer,
+``h`` a Mamba2 layer that a shared block feeds.  The a-th application uses
+block ``a % n_shared_blocks`` and its own rank-``adapter_rank`` LoRA on the
+block's MLP and its own ``linear``.  At an application:
 
-Simplifications vs the released checkpoints (DESIGN.md): no per-application
-LoRA on the shared block and no embedding-concat at the shared-block input.
+    t = linear_a(block(concat(x, embedding)))     # no residual inside
+    x = x + mamba2(rmsnorm(x + t))                # t enters the norm only
+
+The block is RMSNorm over the concatenation (width ``attn_in``), rotary
+attention with softmax scale ``attn_scale`` and an output projection back to
+d_model, RMSNorm, and a GELU-gated MLP whose ``gate_up`` takes the LoRA.
+Each application keeps its own KV cache at serve time; every Mamba2 layer
+its own recurrent state.
+
+Parameters: ``runs`` are the stacked Mamba2 layers between applications
+(``runs[0]`` before the first, ``runs[a + 1]`` after the a-th; a run may be
+empty), scanned; ``apps[a]`` the a-th application's Mamba2 layer, LoRA and
+``linear``; ``blocks`` the shared blocks.
 """
 from __future__ import annotations
 
@@ -18,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import DEFAULT_NEW_TOKENS, ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M2
 
@@ -26,167 +36,192 @@ F32 = jnp.float32
 Params = Any
 
 
+def runs_of(cfg: ModelConfig):
+    """(start, stop) of the runs of plain Mamba2 layers around the
+    applications: len(hybrid_layers) + 1 runs, any of them possibly empty."""
+    edges = (-1,) + cfg.hybrid_layers + (cfg.n_layers,)
+    return [(lo + 1, hi) for lo, hi in zip(edges, edges[1:])]
+
+
 class Zamba:
     def __init__(self, cfg: ModelConfig, *, remat: str = "full",
                  kv_block: int = 512, seq_chunk: int = 2048):
-        assert cfg.family == "hybrid"
+        assert cfg.family == "hybrid" and cfg.n_shared_blocks
         self.cfg = cfg
         self.remat = remat
         self.kv_block = kv_block
         self.seq_chunk = seq_chunk
         self.dtype = jnp.dtype(cfg.dtype)
-        self.n_groups = cfg.n_layers // cfg.attn_every
-        self.tail = cfg.n_layers % cfg.attn_every
+        self.runs = runs_of(cfg)
+        self.n_apps = len(cfg.hybrid_layers)
 
     def _maybe_remat(self, fn):
         return fn if self.remat == "none" else jax.checkpoint(fn)
 
+    # -- parameters ------------------------------------------------------------
+
+    def _block_params(self, rng):
+        cfg, dtype = self.cfg, self.dtype
+        r = L.split_rngs(rng, 3)
+        return {
+            "ln": L.rmsnorm_params(cfg.attn_in, dtype),
+            "attn": L.attention_params(cfg, r[0], dtype),
+            "ffn_ln": L.rmsnorm_params(cfg.d_model, dtype),
+            "gate_up": L._dense_init(r[1], (cfg.d_model, 2 * cfg.d_ff), dtype),
+            "down_proj": L._dense_init(r[2], (cfg.d_ff, cfg.d_model), dtype),
+        }
+
+    def _app_params(self, rng, layer_rng):
+        cfg, dtype = self.cfg, self.dtype
+        r = L.split_rngs(rng, 3)
+        return {
+            "mamba": M2.mamba2_params(cfg, layer_rng, dtype),
+            "lora_a": L._dense_init(r[0], (cfg.d_model, cfg.adapter_rank),
+                                    dtype),
+            "lora_b": L._dense_init(r[1], (cfg.adapter_rank, 2 * cfg.d_ff),
+                                    dtype),
+            "linear": L._dense_init(r[2], (cfg.d_model, cfg.d_model), dtype),
+        }
+
     def init(self, rng) -> Params:
         cfg, dtype = self.cfg, self.dtype
-        g, k, t = self.n_groups, cfg.attn_every, self.tail
-        r_e, r_m, r_t, r_a, r_f = jax.random.split(rng, 5)
-        rm = jax.random.split(r_m, g * k).reshape(g, k)
-        p = {
+        r_e, r_l, r_b, r_a = jax.random.split(rng, 4)
+        layer = jax.random.split(r_l, cfg.n_layers)
+        return {
             "embed": L.embed_params(cfg, r_e, dtype),
-            "mamba": jax.vmap(jax.vmap(
-                lambda r: M2.mamba2_params(cfg, r, dtype)))(rm),
-            "attn_ln": L.rmsnorm_params(cfg.d_model, dtype),
-            "attn": L.attention_params(cfg, r_a, dtype),
-            "attn_mlp_ln": L.rmsnorm_params(cfg.d_model, dtype),
-            "attn_mlp": L.mlp_params(cfg.d_model, cfg.d_ff, r_f, dtype),
+            "runs": tuple(
+                jax.vmap(lambda r: M2.mamba2_params(cfg, r, dtype))(
+                    layer[lo:hi]) for lo, hi in self.runs),
+            "apps": tuple(
+                self._app_params(r, layer[i]) for r, i in zip(
+                    jax.random.split(r_a, self.n_apps), cfg.hybrid_layers)),
+            "blocks": tuple(self._block_params(r) for r in jax.random.split(
+                r_b, cfg.n_shared_blocks)),
             "ln_f": L.rmsnorm_params(cfg.d_model, dtype),
         }
-        if t:
-            rt = jax.random.split(r_t, t)
-            p["mamba_tail"] = jax.vmap(
-                lambda r: M2.mamba2_params(cfg, r, dtype))(rt)
-        return p
 
     def init_abstract(self):
         return jax.eval_shape(self.init, jax.random.key(0))
 
-    def _shared_attn(self, params, x, positions, cache=None, window=None):
+    # -- layers ----------------------------------------------------------------
+
+    def _run(self, stack, x, states=None, emit=False):
+        """The Mamba2 layers of one run: decode against ``states``, or the
+        whole sequence, emitting the states with ``emit``."""
         cfg = self.cfg
-        h, new_cache = L.attention_apply(
-            cfg, params["attn"], L.rmsnorm(params["attn_ln"], x, cfg.norm_eps),
-            positions, cache=cache, kv_block=self.kv_block, window=window)
-        x = x + h
-        x = x + L.mlp_apply(params["attn_mlp"],
-                            L.rmsnorm(params["attn_mlp_ln"], x, cfg.norm_eps))
-        return x, new_cache
+        with jax.named_scope("zamba2.mamba"):
+            if states is not None:
+                def step(xi, inp):
+                    return M2.mamba2_decode(cfg, inp[0], xi, inp[1])
+                return lax.scan(step, x, (stack, states))
 
-    def backbone(self, params, x, positions, *, window=None):
+            def body(xi, p):
+                if emit:
+                    return M2.mamba2_apply(cfg, p, xi, return_state=True)
+                return M2.mamba2_apply(cfg, p, xi), None
+            return lax.scan(self._maybe_remat(body), x, stack)
+
+    def _shared(self, params, a, x, emb, pos, kv=None, n_gen=None):
+        """The a-th application's contribution ``t`` (and its KV cache)."""
         cfg = self.cfg
+        blk = params["blocks"][a % cfg.n_shared_blocks]
+        app = params["apps"][a]
+        with jax.named_scope("zamba2.shared_block"):
+            h = L.rmsnorm(blk["ln"], jnp.concatenate([x, emb], axis=-1),
+                          cfg.norm_eps)
+            h, kv = L.attention_apply(cfg, blk["attn"], h, pos, cache=kv,
+                                      n_gen=n_gen, kv_block=self.kv_block)
+            h = L.rmsnorm(blk["ffn_ln"], h, cfg.norm_eps)
+            gate_up = jnp.einsum("bsd,df->bsf", h, blk["gate_up"])
+        with jax.named_scope("zamba2.adapter"):
+            gate_up = gate_up + jnp.einsum(
+                "bsr,rf->bsf", jnp.einsum("bsd,dr->bsr", h, app["lora_a"]),
+                app["lora_b"])
+        with jax.named_scope("zamba2.shared_block"):
+            gate, up = jnp.split(gate_up, 2, axis=-1)
+            y = jax.nn.gelu(gate.astype(F32), approximate=False) \
+                .astype(up.dtype) * up
+            y = jnp.einsum("bsf,fd->bsd", y, blk["down_proj"])
+        with jax.named_scope("zamba2.linear"):
+            t = jnp.einsum("bsd,de->bse", y, app["linear"])
+        return t, kv
 
-        def group(xc, mp):
-            xc, _ = self._shared_attn(params, xc, positions, window=window)
+    def _hybrid(self, params, a, x, t, state=None, emit=False):
+        """The a-th application's Mamba2 layer, fed ``t``."""
+        cfg = self.cfg
+        p = params["apps"][a]["mamba"]
+        with jax.named_scope("zamba2.mamba"):
+            if state is not None:
+                return M2.mamba2_decode(cfg, p, x, state, extra=t)
+            if emit:
+                return M2.mamba2_apply(cfg, p, x, extra=t, return_state=True)
+            return M2.mamba2_apply(cfg, p, x, extra=t), None
 
-            def m_body(xi, mpi):
-                return M2.mamba2_apply(cfg, mpi, xi), None
-            xc, _ = lax.scan(self._maybe_remat(m_body), xc, mp)
-            return xc, None
+    def _forward(self, params, tokens, pos, cache=None, n_gen=None):
+        """Final hidden states and the new decode state.  With ``cache``
+        one token is decoded; with ``n_gen`` a prefill emits the state with
+        KV caches of prompt + ``n_gen`` slots; with neither, training."""
+        cfg = self.cfg
+        emb = L.embed_lookup(params["embed"], tokens)
+        emit = cache is not None or n_gen is not None
 
-        x, _ = lax.scan(self._maybe_remat(group), x, params["mamba"])
-        if self.tail:
-            def t_body(xi, mpi):
-                return M2.mamba2_apply(cfg, mpi, xi), None
-            x, _ = lax.scan(self._maybe_remat(t_body), x, params["mamba_tail"])
-        return L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        def app(a, x, kv, state):
+            t, kv = self._shared(params, a, x, emb, pos, kv, n_gen)
+            x, state = self._hybrid(params, a, x, t, state, emit)
+            return x, (kv, state)
+
+        if cache is None and self.remat != "none":
+            app = jax.checkpoint(app, static_argnums=(0,))
+        x = emb
+        kvs, hybrid, runs = [], [], []
+        for a, stack in enumerate(params["runs"]):
+            if a:
+                x, (kv, state) = app(
+                    a - 1, x, None if cache is None else cache["kv"][a - 1],
+                    None if cache is None else cache["hybrid"][a - 1])
+                kvs.append(kv)
+                hybrid.append(state)
+            x, states = self._run(
+                stack, x, None if cache is None else cache["runs"][a], emit)
+            runs.append(states)
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        new = {"kv": tuple(kvs), "hybrid": tuple(hybrid), "runs": tuple(runs)}
+        return x, (new if emit else None)
+
+    # -- train -----------------------------------------------------------------
 
     def loss_fn(self, params, batch):
-        cfg = self.cfg
         tokens, labels = batch["tokens"], batch["labels"]
         b, s = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-        # training at 4k: window >= seq ⇒ effectively full attention
-        x = self.backbone(params, x, pos, window=0)
-        return L.chunked_lm_loss(cfg, params["embed"], x, labels,
+        x, _ = self._forward(params, tokens, pos)
+        return L.chunked_lm_loss(self.cfg, params["embed"], x, labels,
                                  self.seq_chunk)
 
-    # -- serve -------------------------------------------------------------
+    # -- serve -----------------------------------------------------------------
 
     def init_cache(self, batch: int, seq_len: int) -> dict:
         cfg = self.cfg
-        g, k, t = self.n_groups, cfg.attn_every, self.tail
-        cap = min(seq_len, cfg.sliding_window) if cfg.sliding_window else seq_len
-        attn_cache = L.empty_cache(cfg, batch, cap, self.dtype, n_layers=g)
-        mstate = M2.empty_state(cfg, batch, self.dtype)
-        mamba = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (g, k) + a.shape).copy(), mstate)
-        out = {"attn": attn_cache, "mamba": mamba}
-        if t:
-            out["mamba_tail"] = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (t,) + a.shape).copy(), mstate)
-        return out
+        state = M2.empty_state(cfg, batch, self.dtype)
 
-    def prefill(self, params, batch):
-        cfg = self.cfg
+        def stacked(n):
+            return jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), state)
+        return {
+            "kv": tuple(L.empty_cache(cfg, batch, seq_len, self.dtype)
+                        for _ in range(self.n_apps)),
+            "hybrid": tuple(M2.empty_state(cfg, batch, self.dtype)
+                            for _ in range(self.n_apps)),
+            "runs": tuple(stacked(hi - lo) for lo, hi in self.runs),
+        }
+
+    def prefill(self, params, batch, n_gen: int = DEFAULT_NEW_TOKENS):
         tokens = batch["tokens"]
         b, s = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-        win = cfg.sliding_window if s > cfg.sliding_window else 0
-
-        def group(xc, mp):
-            h_in = L.rmsnorm(params["attn_ln"], xc, cfg.norm_eps)
-            q, k, v = L._project_qkv(cfg, params["attn"], h_in, pos,
-                                     cfg.rope_theta)
-            out = L.blockwise_attention(q, k, v, pos, pos, window=win,
-                                        kv_block=self.kv_block)
-            xc = xc + jnp.einsum("bshe,hed->bsd", out, params["attn"]["wo"])
-            xc = xc + L.mlp_apply(
-                params["attn_mlp"],
-                L.rmsnorm(params["attn_mlp_ln"], xc, cfg.norm_eps))
-            a_cache = L.init_cache_from(cfg, k, v, pos, cfg.sliding_window)
-
-            def m_body(xi, mpi):
-                xi, st = M2.mamba2_apply(cfg, mpi, xi, return_state=True)
-                return xi, st
-            xc, m_states = lax.scan(self._maybe_remat(m_body), xc, mp)
-            return xc, (a_cache, m_states)
-
-        x, (attn_cache, m_states) = lax.scan(self._maybe_remat(group), x,
-                                             params["mamba"])
-        out = {"attn": attn_cache, "mamba": m_states}
-        if self.tail:
-            def t_body(xi, mpi):
-                xi, st = M2.mamba2_apply(cfg, mpi, xi, return_state=True)
-                return xi, st
-            x, t_states = lax.scan(self._maybe_remat(t_body), x,
-                                   params["mamba_tail"])
-            out["mamba_tail"] = t_states
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x[:, -1:, :])
-        return logits, out
+        x, cache = self._forward(params, tokens, pos, n_gen=n_gen)
+        return L.unembed(self.cfg, params["embed"], x[:, -1:, :]), cache
 
     def decode_step(self, params, cache, tokens, pos):
-        cfg = self.cfg
-        x = L.embed_lookup(params["embed"], tokens)
-
-        def group(xc, gp):
-            mp, ac, mst = gp
-            xi, new_ac = self._shared_attn(params, xc, pos, cache=ac,
-                                           window=cfg.sliding_window)
-
-            def m_body(xj, inp):
-                mpi, sti = inp
-                xj, st = M2.mamba2_decode(cfg, mpi, xj, sti)
-                return xj, st
-            xi, new_m = lax.scan(m_body, xi, (mp, mst))
-            return xi, (new_ac, new_m)
-
-        x, (new_attn, new_mamba) = lax.scan(
-            group, x, (params["mamba"], cache["attn"], cache["mamba"]))
-        new_cache = {"attn": new_attn, "mamba": new_mamba}
-        if self.tail:
-            def t_body(xj, inp):
-                mpi, sti = inp
-                xj, st = M2.mamba2_decode(cfg, mpi, xj, sti)
-                return xj, st
-            x, new_t = lax.scan(t_body, x,
-                                (params["mamba_tail"], cache["mamba_tail"]))
-            new_cache["mamba_tail"] = new_t
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x)
-        return logits, new_cache
+        x, cache = self._forward(params, tokens, pos, cache=cache)
+        return L.unembed(self.cfg, params["embed"], x), cache

@@ -135,29 +135,39 @@ def _serve(arch, batch, prompt_len):
     return prefill, decode, params, batch
 
 
-# the chip smoke's xlstm serving shapes and the whisper serving cell's
-SERVE_SHAPES = {XLSTM: (8, 1024), "whisper-tiny": (16, 4)}
+ZAMBA = "zamba2-7b-18l"
+# (batch, prompt, new tokens): the chip smoke's xlstm serving shape and the
+# whisper and zamba2 serving cells'
+SERVE_SHAPES = {XLSTM: (8, 1024, 64), "whisper-tiny": (16, 4, 64),
+                ZAMBA: (16, 2048, 128)}
 
 
 def _compile_step(one_chip, arch, step, greedy=False):
     """Compile the prefill or decode of ``arch`` for one v5e chip, the
     logits step or the served one with greedy sampling folded in; the
-    step's arguments and temporaries must fit the chip's HBM."""
-    b, prompt_len = SERVE_SHAPES[arch]
+    step's arguments, outputs (those not written in place of a donated
+    argument) and temporaries, and during decode the replica's copy of the
+    decode state (it lives on the same device), must fit the chip's HBM."""
+    b, prompt_len, n_gen = SERVE_SHAPES[arch]
     prefill, decode, params, batch = _serve(arch, b, prompt_len)
+    replica = 0
     if step == "prefill":
-        fn, args, donate = prefill, (params, batch), ()
-        wrap = greedy_prefill
+        fn = greedy_prefill(prefill) if greedy else prefill
+        lowered = jax.jit(fn, static_argnums=(2,)).lower(
+            *_on(one_chip, (params, batch)), n_gen)
     else:
-        _, cache = jax.eval_shape(prefill, params, batch)
+        _, cache = jax.eval_shape(functools.partial(prefill, n_gen=n_gen),
+                                  params, batch)
         tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
-        fn, args, donate = decode, (params, cache, tok, tok), (1,)
-        wrap = greedy_decode
-    fn = wrap(fn) if greedy else fn
-    compiled = jax.jit(fn, donate_argnums=donate).lower(
-        *_on(one_chip, args)).compile()
+        fn = greedy_decode(decode) if greedy else decode
+        lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+            *_on(one_chip, (params, cache, tok, tok)))
+        replica = _nbytes(cache)
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes + replica \
+        < HBM_BYTES
     return compiled
 
 
@@ -167,6 +177,18 @@ def test_xlstm_prefill_compiles_for_v5e(one_chip):
 
 def test_xlstm_decode_compiles_for_v5e(one_chip):
     _compile_step(one_chip, XLSTM, "decode")
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_zamba2_serving_steps_fit_v5e(one_chip, step):
+    """The zamba2 serving cell's steps at full width, batch 16 with KV
+    caches of 2048 + 128 slots: they compile for one v5e, and each with
+    its temporaries, and the decode with the replica's copy of the KV
+    caches and Mamba2 states, fits the chip's 16 GiB."""
+    compiled = _compile_step(one_chip, ZAMBA, step, greedy=True)
+    _, _, params, _ = _serve(ZAMBA, 16, 2048)
+    assert compiled.memory_analysis().argument_size_in_bytes >= \
+        _nbytes(params)
 
 
 # ------------------------------------------------- argmax apart from the
