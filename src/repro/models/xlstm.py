@@ -139,8 +139,11 @@ def mlstm_decode(cfg: ModelConfig, p: Params, x, state):
     q, k, v = (a[:, 0].astype(F32) for a in (q, k, v))    # [B,H,dh]
     i_t = ig[:, 0]                                        # [B,H]
     f_t = jnp.exp(fg[:, 0])
+    # the outer product (k i) v^T as a broadcast product: the same f32
+    # products as an einsum, but the compiler fuses it into the in-place
+    # write of the state instead of building each block's new C apart
     c = state["C"].astype(F32) * f_t[:, :, None, None] + \
-        jnp.einsum("bhd,bhe,bh->bhde", k, v, i_t)
+        (k * i_t[:, :, None])[:, :, :, None] * v[:, :, None, :]
     n = state["n"].astype(F32) * f_t[:, :, None] + k * i_t[:, :, None]
     num = jnp.einsum("bhd,bhde->bhe", q, c)
     den = jnp.maximum(jnp.abs(jnp.einsum("bhd,bhd->bh", q, n)), 1.0)
@@ -322,23 +325,39 @@ class XLSTM:
         return logits, {"mlstm": m_states, "slstm": s_states}
 
     def decode_step(self, params, cache, tokens, pos):
+        """One token through every block.  The stacked state rides in the
+        loop carry and each block's slice is read and written back in
+        place, so a donated cache is updated where it lies: no copy of the
+        whole state into or out of a scan."""
         cfg = self.cfg
         x = L.embed_lookup(params["embed"], tokens)
 
-        def group(xc, gp):
-            mp, sp, mst, sst = gp
+        def at(tree, *idx):
+            return jax.tree.map(lambda a: a[idx], tree)
 
-            def m_body(xi, inp):
-                mpi, sti = inp
-                xi, st = mlstm_decode(cfg, mpi, xi, sti)
-                return xi, st
-            xc, new_m = lax.scan(m_body, xc, (mp, mst))
-            xc, new_s = slstm_apply(cfg, sp, xc, state=sst, return_state=True)
-            return xc, (new_m, new_s)
+        def put(tree, new, *idx):
+            # not ``.at[idx].set``: its scatter's bounds check makes the
+            # compiler copy the block out before the update
+            return jax.tree.map(
+                lambda a, v: lax.dynamic_update_slice(
+                    a, v[(None,) * len(idx)], idx + (0,) * v.ndim),
+                tree, new)
 
-        x, (new_m, new_s) = lax.scan(
-            group, x, (params["mlstm"], params["slstm"],
-                       cache["mlstm"], cache["slstm"]))
+        def group(g, carry):
+            xc, m, s = carry
+
+            def m_layer(j, c):
+                xc, m = c
+                xc, st = mlstm_decode(cfg, at(params["mlstm"], g, j), xc,
+                                      at(m, g, j))
+                return xc, put(m, st, g, j)
+            xc, m = lax.fori_loop(0, self.m_per_group, m_layer, (xc, m))
+            xc, st = slstm_apply(cfg, at(params["slstm"], g), xc,
+                                 state=at(s, g), return_state=True)
+            return xc, m, put(s, st, g)
+
+        x, m, s = lax.fori_loop(0, self.n_groups, group,
+                                (x, cache["mlstm"], cache["slstm"]))
         x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits = L.unembed(cfg, params["embed"], x)
-        return logits, {"mlstm": new_m, "slstm": new_s}
+        return logits, {"mlstm": m, "slstm": s}

@@ -272,3 +272,40 @@ def test_served_steps_keep_the_projection_out_of_the_argmax(one_chip, arch,
     found = _argmax_fusions(compiled.as_text())
     assert found, "no argmax fusion in the compiled step"
     assert not any(found.values()), found
+
+
+# ------------------------------------------- xlstm's state written in place
+
+def test_xlstm_cell_decode_writes_the_state_in_place_on_v5e(one_chip):
+    """The xlstm serving cell's decode (batch 32) as the v5e compiler
+    builds it: outside fused computations, the one instruction that yields
+    an f32 array of mLSTM ``C`` blocks ([..., 32, 4, 256, 256]) is a
+    fusion whose root updates a block of the donated stacked state in
+    place.  No copy or fresh buffer of the state, of a group of it or of
+    one block is made; the block's old value is read inside that fusion
+    and by the readout, never stored apart."""
+    b = 32
+    prefill, decode, params, batch = _serve(XLSTM, b, 1024)
+    _, cache = jax.eval_shape(functools.partial(prefill, n_gen=128),
+                              params, batch)
+    tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+    hlo = jax.jit(greedy_decode(decode), donate_argnums=(1,)).lower(
+        *_on(one_chip, (params, cache, tok, tok))).compile().as_text()
+    full = "f32[" + ",".join(map(str, cache["mlstm"]["C"].shape)) + "]"
+    block = re.compile(r"f32\[[\d,]*32,4,256,256\]")
+    comps = _computations(hlo)
+    made = []
+    for name, lines in comps.items():
+        if name.startswith("fused"):
+            continue
+        for line in map(str.strip, lines):
+            m = re.match(r"(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+            if m and block.search(m.group(2)) and m.group(3) not in (
+                    "parameter", "get-tuple-element", "tuple", "while"):
+                made.append((m.group(3), m.group(2), line))
+    assert len(made) == 1, [line[:160] for *_, line in made]
+    kind, shape, line = made[0]
+    assert kind == "fusion" and shape.startswith(full + "{"), line[:160]
+    root = [x for x in map(str.strip, comps[re.search(
+        r"calls=%([\w.\-]+)", line).group(1)]) if x.startswith("ROOT ")]
+    assert " dynamic-update-slice(" in root[0]
