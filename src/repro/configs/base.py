@@ -43,7 +43,6 @@ class ModelConfig:
     # --- dense feature flags -------------------------------------------------
     qk_norm: bool = False             # qwen3
     qkv_bias: bool = False            # qwen1.5
-    attn_out_bias: bool = False
     sliding_window: int = 0           # 0 -> full attention (mixtral: 4096)
     rope_theta: float = 1e4
     tie_embeddings: bool = False

@@ -270,11 +270,13 @@ def attention_apply(
     kv_block: int = 512,
     use_rope: bool = True,
     window: Optional[int] = None,
+    causal: bool = True,
 ):
     """Returns (y, new_cache).  With ``cache`` one token is decoded against
-    it; otherwise the whole sequence attends causally, and with ``n_gen``
-    the prefill emits a cache with room for ``n_gen`` decoded tokens.
-    The softmax scale is ``cfg.attn_scale`` where set."""
+    it; otherwise the whole sequence attends (causally unless ``causal`` is
+    False, as in an encoder), and with ``n_gen`` the prefill emits a cache
+    with room for ``n_gen`` decoded tokens.  The softmax scale is
+    ``cfg.attn_scale`` where set."""
     theta = cfg.rope_theta if use_rope else 0.0
     win = cfg.sliding_window if window is None else window
     scale = cfg.attn_scale or None
@@ -282,7 +284,8 @@ def attention_apply(
 
     if cache is None:
         out = blockwise_attention(q, k, v, positions, positions,
-                                  window=win, kv_block=kv_block, scale=scale)
+                                  window=win, kv_block=kv_block,
+                                  causal=causal, scale=scale)
         new_cache = None if n_gen is None else \
             init_cache_from(cfg, k, v, positions, win, n_gen)
     else:
@@ -299,8 +302,6 @@ def attention_apply(
                      "idx": cache["idx"] + 1}
 
     y = jnp.einsum("bshe,hed->bsd", out, p["wo"])
-    if cfg.attn_out_bias and "bo" in p:
-        y = y + p["bo"]
     return y, new_cache
 
 

@@ -12,8 +12,7 @@ over self layers.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -50,10 +49,10 @@ def _layer_params(cfg: ModelConfig, rng, dtype) -> Params:
 
 
 def _layer_apply(cfg: ModelConfig, lp: Params, x, positions, *, cache=None,
-                 kv_block=512, window=None):
+                 n_gen=None, kv_block=512):
     h, new_cache = L.attention_apply(
         cfg, lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), positions,
-        cache=cache, kv_block=kv_block, window=window)
+        cache=cache, n_gen=n_gen, kv_block=kv_block)
     x = x + h
     x = x + _ffn_apply(cfg, lp["ffn"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
     return x, new_cache
@@ -107,31 +106,46 @@ class Transformer:
             policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
         return jax.checkpoint(fn, policy=policy)
 
-    def backbone(self, params: Params, x, positions, *, image_embeds=None):
-        """Full-sequence forward (train / prefill w/o cache emission)."""
+    def _forward(self, params: Params, tokens, positions, *, cache=None,
+                 n_gen=None, image_embeds=None):
+        """Final hidden states and the new cache.  With ``cache`` one token
+        is decoded; with ``n_gen`` a prefill emits a cache with room for
+        ``n_gen`` decoded tokens; with neither, training."""
         cfg = self.cfg
+        remat = self._maybe_remat if cache is None else (lambda fn: fn)
+        x = L.embed_lookup(params["embed"], tokens)
+
+        def self_layers(xc, lp, caches):
+            def body(xi, lc):
+                lpi, ci = lc
+                return _layer_apply(cfg, lpi, xi, positions, cache=ci,
+                                    n_gen=n_gen, kv_block=self.kv_block)
+            return lax.scan(remat(body), xc, (lp, caches))
 
         if cfg.family == "vlm":
+            # the image memory's K/V: from the cache when decoding, else
+            # computed (and, in a prefill, emitted) once per group
             def group(xc, gp):
-                lp, cp = gp
-                kv = L.cross_attention_kv(cfg, cp, image_embeds)
+                lp, cp, ckv, sc = gp
+                if ckv is None:
+                    kv = L.cross_attention_kv(cfg, cp, image_embeds)
+                else:
+                    kv = (ckv["k"], ckv["v"])
                 xc = xc + L.cross_attention_apply(cfg, cp, xc, kv=kv)
-
-                def self_layer(xi, lpi):
-                    xi, _ = _layer_apply(cfg, lpi, xi, positions,
-                                         kv_block=self.kv_block)
-                    return xi, None
-                xc, _ = lax.scan(self._maybe_remat(self_layer), xc, lp)
-                return xc, None
-            x, _ = lax.scan(self._maybe_remat(group), x,
-                            (params["layers"], params["cross"]))
+                xc, new_sc = self_layers(xc, lp, sc)
+                return xc, (new_sc, None if n_gen is None else kv)
+            cross, self_cache = (None, None) if cache is None else \
+                (cache["cross"], cache["self"])
+            x, (self_cache, kvs) = lax.scan(
+                remat(group), x,
+                (params["layers"], params["cross"], cross, self_cache))
+            if kvs is not None:
+                cross = {"k": kvs[0], "v": kvs[1]}
+            new_cache = {"self": self_cache, "cross": cross}
         else:
-            def body(xc, lp):
-                xc, _ = _layer_apply(cfg, lp, xc, positions,
-                                     kv_block=self.kv_block)
-                return xc, None
-            x, _ = lax.scan(self._maybe_remat(body), x, params["layers"])
-        return L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+            x, new_cache = self_layers(x, params["layers"], cache)
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        return x, new_cache
 
     # -- train ----------------------------------------------------------------
 
@@ -140,9 +154,8 @@ class Transformer:
         tokens, labels = batch["tokens"], batch["labels"]
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-        x = self.backbone(params, x, positions,
-                          image_embeds=batch.get("image_embeds"))
+        x, _ = self._forward(params, tokens, positions,
+                             image_embeds=batch.get("image_embeds"))
         loss = L.chunked_lm_loss(cfg, params["embed"], x, labels,
                                  self.seq_chunk)
         if cfg.n_experts:
@@ -183,88 +196,16 @@ class Transformer:
                 n_gen: int = DEFAULT_NEW_TOKENS):
         """Process the full prompt; return (last_logits, cache) with room
         for ``n_gen`` decoded tokens."""
-        cfg = self.cfg
         tokens = batch["tokens"]
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-        image_embeds = batch.get("image_embeds")
-
-        if cfg.family == "vlm":
-            def group(xc, gp):
-                lp, cp = gp
-                kv = L.cross_attention_kv(cfg, cp, image_embeds)
-                xc = xc + L.cross_attention_apply(cfg, cp, xc, kv=kv)
-
-                def self_layer2(xi, lpi):
-                    h_in = L.rmsnorm(lpi["ln1"], xi, cfg.norm_eps)
-                    q, k, v = L._project_qkv(cfg, lpi["attn"], h_in, positions,
-                                             cfg.rope_theta)
-                    out = L.blockwise_attention(
-                        q, k, v, positions, positions,
-                        window=cfg.sliding_window, kv_block=self.kv_block)
-                    h = jnp.einsum("bshe,hed->bsd", out, lpi["attn"]["wo"])
-                    xi = xi + h
-                    xi = xi + _ffn_apply(cfg, lpi["ffn"],
-                                         L.rmsnorm(lpi["ln2"], xi, cfg.norm_eps))
-                    return xi, L.init_cache_from(cfg, k, v, positions,
-                                                 cfg.sliding_window, n_gen)
-                xc, caches = lax.scan(self._maybe_remat(self_layer2), xc, lp)
-                return xc, (caches, kv)
-            x, (self_caches, cross_kvs) = lax.scan(
-                self._maybe_remat(group), x, (params["layers"], params["cross"]))
-            cache = {"self": self_caches,
-                     "cross": {"k": cross_kvs[0], "v": cross_kvs[1]}}
-        else:
-            def body(xc, lp):
-                h_in = L.rmsnorm(lp["ln1"], xc, cfg.norm_eps)
-                q, k, v = L._project_qkv(cfg, lp["attn"], h_in, positions,
-                                         cfg.rope_theta)
-                out = L.blockwise_attention(
-                    q, k, v, positions, positions,
-                    window=cfg.sliding_window, kv_block=self.kv_block)
-                h = jnp.einsum("bshe,hed->bsd", out, lp["attn"]["wo"])
-                xc = xc + h
-                xc = xc + _ffn_apply(cfg, lp["ffn"],
-                                     L.rmsnorm(lp["ln2"], xc, cfg.norm_eps))
-                return xc, L.init_cache_from(cfg, k, v, positions,
-                                             cfg.sliding_window, n_gen)
-            x, cache = lax.scan(self._maybe_remat(body), x, params["layers"])
-
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x[:, -1:, :])
+        x, cache = self._forward(params, tokens, positions, n_gen=n_gen,
+                                 image_embeds=batch.get("image_embeds"))
+        logits = L.unembed(self.cfg, params["embed"], x[:, -1:, :])
         return logits, cache
 
     def decode_step(self, params: Params, cache, tokens, pos):
         """tokens: [B, 1]; pos: [B, 1] absolute positions."""
-        cfg = self.cfg
-        x = L.embed_lookup(params["embed"], tokens)
-
-        if cfg.family == "vlm":
-            def group_body(xc, gp):
-                lp, cp, ckv, sc = gp
-                xc = xc + L.cross_attention_apply(cfg, cp, xc,
-                                                  kv=(ckv["k"], ckv["v"]))
-                def self_layer(xi, lc):
-                    lpi, ci = lc
-                    xi, nc = _layer_apply(cfg, lpi, xi, pos, cache=ci,
-                                          kv_block=self.kv_block)
-                    return xi, nc
-                xc, new_sc = lax.scan(self_layer, xc, (lp, sc))
-                return xc, new_sc
-            x, new_self = lax.scan(
-                group_body, x,
-                (params["layers"], params["cross"], cache["cross"],
-                 cache["self"]))
-            new_cache = {"self": new_self, "cross": cache["cross"]}
-        else:
-            def body(xc, lc):
-                lp, ci = lc
-                xi, nc = _layer_apply(cfg, lp, xc, pos, cache=ci,
-                                      kv_block=self.kv_block)
-                return xi, nc
-            x, new_cache = lax.scan(body, x, (params["layers"], cache))
-
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x)
+        x, new_cache = self._forward(params, tokens, pos, cache=cache)
+        logits = L.unembed(self.cfg, params["embed"], x)
         return logits, new_cache

@@ -94,11 +94,10 @@ class Whisper:
         pos = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), (b, m))
 
         def body(x, lp):
-            h_in = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
-            q, k, v = L._project_qkv(cfg, lp["attn"], h_in, pos, cfg.rope_theta)
-            out = L.blockwise_attention(q, k, v, pos, pos, window=0,
-                                        kv_block=self.kv_block, causal=False)
-            x = x + jnp.einsum("bshe,hed->bsd", out, lp["attn"]["wo"])
+            h, _ = L.attention_apply(
+                cfg, lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), pos,
+                kv_block=self.kv_block, window=0, causal=False)
+            x = x + h
             x = x + _gelu_mlp(lp["mlp"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
             return x, None
 
@@ -108,11 +107,12 @@ class Whisper:
 
     # -- decoder ---------------------------------------------------------------
 
-    def _dec_layer(self, lp, x, positions, memory_kv, cache=None):
+    def _dec_layer(self, lp, x, positions, memory_kv, cache=None,
+                   n_gen=None):
         cfg = self.cfg
         h, new_cache = L.attention_apply(
             cfg, lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), positions,
-            cache=cache, kv_block=self.kv_block, window=0)
+            cache=cache, n_gen=n_gen, kv_block=self.kv_block, window=0)
         x = x + h
         x = x + L.cross_attention_apply(
             cfg, lp["xattn"], L.rmsnorm(lp["ln_x"], x, cfg.norm_eps),
@@ -120,54 +120,53 @@ class Whisper:
         x = x + _gelu_mlp(lp["mlp"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
         return x, new_cache
 
-    def loss_fn(self, params: Params, batch: dict) -> jnp.ndarray:
+    def _decoder(self, params: Params, tokens, pos, *, memory=None,
+                 cache=None, n_gen=None):
+        """Final decoder hidden states and the new cache.  With ``cache``
+        one token is decoded against it; otherwise the tokens attend to
+        ``memory``, and with ``n_gen`` a prefill emits a cache with room
+        for ``n_gen`` decoded tokens; with neither, training."""
         cfg = self.cfg
+        x = L.embed_lookup(params["embed"], tokens)
+
+        def body(xc, lc):
+            lp, sc, ckv = lc
+            if ckv is None:
+                kv = L.cross_attention_kv(cfg, lp["xattn"], memory)
+            else:
+                kv = (ckv["k"], ckv["v"])
+            xc, nc = self._dec_layer(lp, xc, pos, kv, cache=sc, n_gen=n_gen)
+            return xc, (nc, None if n_gen is None else kv)
+
+        remat = self._maybe_remat if cache is None else (lambda fn: fn)
+        self_cache, cross = (None, None) if cache is None else \
+            (cache["self"], cache["cross"])
+        x, (self_cache, kvs) = lax.scan(
+            remat(body), x, (params["dec_layers"], self_cache, cross))
+        if kvs is not None:
+            cross = {"k": kvs[0], "v": kvs[1]}
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        return x, {"self": self_cache, "cross": cross}
+
+    def loss_fn(self, params: Params, batch: dict) -> jnp.ndarray:
         tokens, labels = batch["tokens"], batch["labels"]
         memory = self.encode(params, batch["frames"])
         b, s = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-
-        def body(xc, lp):
-            kv = L.cross_attention_kv(cfg, lp["xattn"], memory)
-            xc, _ = self._dec_layer(lp, xc, pos, kv)
-            return xc, None
-
-        x, _ = lax.scan(self._maybe_remat(body), x, params["dec_layers"])
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        return L.chunked_lm_loss(cfg, params["embed"], x, labels,
+        x, _ = self._decoder(params, tokens, pos, memory=memory)
+        return L.chunked_lm_loss(self.cfg, params["embed"], x, labels,
                                  self.seq_chunk)
 
     def prefill(self, params: Params, batch: dict,
                 n_gen: int = DEFAULT_NEW_TOKENS):
-        cfg = self.cfg
         tokens = batch["tokens"]
         memory = self.encode(params, batch["frames"])
         b, s = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed_lookup(params["embed"], tokens)
-
-        def body(xc, lp):
-            kv = L.cross_attention_kv(cfg, lp["xattn"], memory)
-            h_in = L.rmsnorm(lp["ln1"], xc, cfg.norm_eps)
-            q, k, v = L._project_qkv(cfg, lp["attn"], h_in, pos, cfg.rope_theta)
-            out = L.blockwise_attention(q, k, v, pos, pos, window=0,
-                                        kv_block=self.kv_block)
-            xc = xc + jnp.einsum("bshe,hed->bsd", out, lp["attn"]["wo"])
-            xc = xc + L.cross_attention_apply(
-                cfg, lp["xattn"], L.rmsnorm(lp["ln_x"], xc, cfg.norm_eps),
-                kv=kv, gated=False)
-            xc = xc + _gelu_mlp(lp["mlp"],
-                                L.rmsnorm(lp["ln2"], xc, cfg.norm_eps))
-            self_cache = L.init_cache_from(cfg, k, v, pos, 0, n_gen)
-            return xc, (self_cache, kv)
-
-        x, (self_cache, cross_kv) = lax.scan(self._maybe_remat(body), x,
-                                             params["dec_layers"])
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x[:, -1:, :])
-        return logits, {"self": self_cache,
-                        "cross": {"k": cross_kv[0], "v": cross_kv[1]}}
+        x, cache = self._decoder(params, tokens, pos, memory=memory,
+                                n_gen=n_gen)
+        logits = L.unembed(self.cfg, params["embed"], x[:, -1:, :])
+        return logits, cache
 
     def init_cache(self, batch: int, seq_len: int) -> dict:
         cfg = self.cfg
@@ -181,17 +180,5 @@ class Whisper:
         return {"self": self_cache, "cross": cross}
 
     def decode_step(self, params: Params, cache, tokens, pos):
-        cfg = self.cfg
-        x = L.embed_lookup(params["embed"], tokens)
-
-        def body(xc, lc):
-            lp, sc, ck, cv = lc
-            xi, nc = self._dec_layer(lp, xc, pos, (ck, cv), cache=sc)
-            return xi, nc
-
-        x, new_self = lax.scan(
-            body, x, (params["dec_layers"], cache["self"],
-                      cache["cross"]["k"], cache["cross"]["v"]))
-        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = L.unembed(cfg, params["embed"], x)
-        return logits, {"self": new_self, "cross": cache["cross"]}
+        x, cache = self._decoder(params, tokens, pos, cache=cache)
+        return L.unembed(self.cfg, params["embed"], x), cache
